@@ -68,7 +68,7 @@ fused >= per-step tokens/s ratio gated in bench_bands.json.
 ``--attn-impl pallas`` adds the ref-vs-pallas comparison row: the same
 workload is served a second time with the Pallas attention kernels
 (partial attention + fused combine under coplace_shmap; interpret mode
-off-TPU, so the CPU row is a correctness row, not a perf row — see
+on CPU, so the CPU row is a correctness row, not a perf row — see
 EXPERIMENTS.md). It reports tok/s for both impls, whether the greedy
 token traces match (exact-tie caveat in EXPERIMENTS.md), and the
 pallas engine's own no-recompile check.
@@ -881,6 +881,7 @@ def run(csv: bool = True, *, requests=24, max_batch=4, gen_min=2,
 
 if __name__ == "__main__":
     from repro.core.layouts import available_layouts
+    from repro.runtime.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=24)
@@ -896,7 +897,7 @@ if __name__ == "__main__":
     ap.add_argument("--attn-impl", choices=["ref", "pallas"], default="ref",
                     help="pallas = add the ref-vs-pallas comparison row "
                          "per layout (Pallas kernels; interpret mode "
-                         "off-TPU)")
+                         "on CPU)")
     ap.add_argument("--prefill-chunk", type=int, default=0,
                     help="add a chunked-prefill engine row per layout "
                          "(N prompt tokens per engine step, interleaved "
@@ -945,6 +946,7 @@ if __name__ == "__main__":
                          "recompile flags, latency percentiles) to PATH, "
                          "e.g. BENCH_serve.json")
     a = ap.parse_args()
+    enable_compile_cache()
     samp = None
     if a.sampling:
         parts = [float(s) for s in a.sampling.split(",")]

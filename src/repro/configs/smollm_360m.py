@@ -1,6 +1,7 @@
-"""SmolLM-360M — llama-arch small dense GQA. [hf:HuggingFaceTB/SmolLM; hf].
+"""SmolLM-360M — llama-arch small dense GQA. [hf:HuggingFaceTB/SmolLM-360M].
 
-32L d_model=960 15H (GQA kv=5) d_ff=2560 vocab=49152.
+32L d_model=960 15H (GQA kv=5) d_ff=2560 vocab=49152, rope_theta=10000
+(the published config.json).
 """
 from repro.configs.base import ArchConfig, register
 
@@ -15,5 +16,6 @@ CONFIG = register(ArchConfig(
     vocab_size=49152,
     head_dim=64,
     tie_embeddings=True,
-    source="hf:HuggingFaceTB/SmolLM-135M; hf",
+    rope_theta=10000.0,
+    source="hf:HuggingFaceTB/SmolLM-360M",
 ))

@@ -692,7 +692,7 @@ def _paged_decode_coplace(spec: AttnSpec, q_r, k_r, v_r,
             sel_v, sel_pos = jax.lax.top_k(v_cat, min(h2.top_k_pages,
                                                       nsh * k_eff))
             sel = jnp.take_along_axis(i_cat, sel_pos, axis=2)
-            sel = jnp.where(sel_v > NEG_INF_HALF, sel, -1)
+            sel = jnp.where(sel_v > paging.SELECTABLE_MIN, sel, -1)
             if sel.shape[2] < h2.top_k_pages:
                 pad = jnp.full(sel.shape[:2] + (h2.top_k_pages
                                                 - sel.shape[2],), -1,
@@ -743,16 +743,14 @@ def _paged_decode_coplace(spec: AttnSpec, q_r, k_r, v_r,
             out = (o_g / jnp.maximum(l_g, 1e-30)[..., None]).astype(q.dtype)
         return out, kp, vp, tmin, tmax, imp, pstart, sel
 
-    from repro.runtime.compat import shard_map as _shard_map
-
     len_spec = vec if ragged else P()
-    shard = _shard_map(
+    shard = jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep, rep, rep, cache5, cache5, cache4, cache4, cache3,
                   cache3, P(bspec, None, None), len_spec) + extra_specs,
         out_specs=(rep, cache5, cache5, cache4, cache4, cache3, cache3,
                    P(bspec, None, None)),
-        check=False,
+        check_vma=False,
     )
     out, kpn, vpn, tminn, tmaxn, impn, pstartn, seln = shard(
         q_r, k_r, v_r, paged.k_pages, paged.v_pages, paged.tau_min,
@@ -762,9 +760,6 @@ def _paged_decode_coplace(spec: AttnSpec, q_r, k_r, v_r,
         k_pages=kpn, v_pages=vpn, tau_min=tminn, tau_max=tmaxn,
         importance=impn, page_start=pstartn, sel_idx=seln)
     return out, new_paged
-
-
-NEG_INF_HALF = -5e29
 
 
 def decode_attention_coplace(spec: AttnSpec, q, k_new, v_new, paged, stream,
@@ -782,10 +777,10 @@ def decode_attention_coplace(spec: AttnSpec, q, k_new, v_new, paged, stream,
     from repro.runtime import hints
 
     mesh = hints.current_mesh()
-    if mesh is None:
-        return decode_attention(spec, q, k_new, v_new, paged, stream,
-                                length, do_select=do_select, perm=perm,
-                                active=active, need_select=need_select)
+    if mesh is None or axis not in mesh.axis_names:
+        raise ValueError(
+            f"decode_attention_coplace needs an ambient mesh with a "
+            f"{axis!r} axis (jax.set_mesh); got {mesh}")
     h2 = spec.h2
     g = spec.group
     nr = spec.n_retrieval

@@ -66,6 +66,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.core import hybrid_attention as hattn
 
@@ -480,9 +481,8 @@ class _GspmdLayout(DefaultLayout):
     ``cache_axes`` (GSPMD partitions the same program differently)."""
 
     def _default_mesh(self, cfg):
-        from repro.runtime.compat import make_mesh
-
-        return make_mesh((1, len(jax.devices())), ("data", "model"))
+        return jax.make_mesh((1, len(jax.devices())), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
 
     def _validate_mesh(self, mesh, axes=("model",)):
         missing = [a for a in axes if a not in mesh.axis_names]
@@ -538,13 +538,12 @@ class InterleaveLayout(CoplaceLayout):
     name = LAYOUT_INTERLEAVE
 
     def _default_mesh(self, cfg):
-        from repro.runtime.compat import make_mesh
-
         n = len(jax.devices())
         # within-page striping needs 'data' | page_size; prefer a real
         # data axis when the device count allows one
         data = 2 if (n % 2 == 0 and cfg.h2eal.page_size % 2 == 0) else 1
-        return make_mesh((data, n // data), ("data", "model"))
+        return jax.make_mesh((data, n // data), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
 
     def plan(self, cfg, mesh=None) -> LayoutPlan:
         plan = super().plan(cfg, mesh)
@@ -557,11 +556,11 @@ class InterleaveLayout(CoplaceLayout):
             return (None, None, "model", "data", None)
         if kind in ("tau", "meta"):
             # Quest min/max metadata + page_start/importance stay
-            # replicated: ~1/page_size of the KV bytes, and the pinned
-            # jax 0.4.x SPMD partitioner miscompiles (or RET_CHECK
-            # fails on) the incremental metadata scatter when their
-            # page dim is sharded inside the scanned ragged decode
-            # body. Only the KV pages themselves are distributed.
+            # replicated: ~1/page_size of the KV bytes, and the SPMD
+            # partitioner has miscompiled (or RET_CHECK failed on) the
+            # incremental metadata scatter when their page dim is
+            # sharded inside the scanned ragged decode body. Only the
+            # KV pages themselves are distributed.
             return (None,) * {"tau": 4, "meta": 3}[kind]
         return super().cache_axes(kind, batch_ok=batch_ok)
 

@@ -34,6 +34,7 @@ from repro.kernels import ops as kops
 
 Array = jax.Array
 NEG_INF = -1e30
+SELECTABLE_MIN = NEG_INF / 2   # scores at or below are masked pages
 
 
 def page_counts(*, sink: int, local: int, page: int) -> tuple[int, int]:
@@ -85,12 +86,18 @@ def score_pages(
 def select_pages(scores: Array, top_k: int) -> Array:
     """Top-k page slots per (B, Hkv): (B, Hkv, K) int32.
 
-    If fewer than ``top_k`` pages exist, the selection is padded with -1
-    sentinels (masked downstream).
+    Picks whose score is masked (sink/local/empty pages, NEG_INF) and
+    picks past the page count are -1 sentinels (masked downstream): a
+    selection only ever holds pages that were selectable when it was
+    made. Without this, a short context's top-k filled up with masked
+    pages in index order, and a page that later slid out of the local
+    window was attended on reuse steps only if its index happened to be
+    among them — a layout-dependent accident (the distributed top-k of
+    ``coplace_shmap`` never kept masked picks).
     """
     k_eff = min(top_k, scores.shape[-1])
-    _, idx = jax.lax.top_k(scores, k_eff)
-    idx = idx.astype(jnp.int32)
+    vals, idx = jax.lax.top_k(scores, k_eff)
+    idx = jnp.where(vals > SELECTABLE_MIN, idx, -1).astype(jnp.int32)
     if k_eff < top_k:
         pad = jnp.full(idx.shape[:-1] + (top_k - k_eff,), -1, jnp.int32)
         idx = jnp.concatenate([idx, pad], axis=-1)

@@ -6,25 +6,27 @@ Two entry points (see docs/kernels.md for the full catalog):
                            with PER-QUERY validity: the (Cq, T) masks the
                            caller derives from absolute positions. The
                            whole chunk's queries stay resident in VMEM as
-                           one (Cq*G, D) operand while KV streams past in
-                           (BT, D) tiles — the Cq == 1 special case is
-                           exactly paged_attention.
+                           G (Cq, D) blocks, one per GQA group member,
+                           each masked by the same (Cq, BT) tile, while
+                           KV streams past in (BT, D) tiles.
   chunk_attention_paged  — the same online-softmax stream with the page
                            gather FUSED into the kernel: instead of a
                            materialized buffer + (B, H, Cq, T) mask, the
-                           grid walks (pages..., chunk) and validity is
-                           computed in-kernel from page_start. Pre-append
-                           cache keys need only per-KEY validity (every
-                           buffered key precedes every chunk query), and
-                           the intra-chunk phase needs only a STATIC
-                           causal mask — no per-query mask ever hits HBM.
+                           grid walks (KV tiles of the paged buffer...,
+                           chunk). Pre-append cache keys need only per-KEY
+                           validity (every buffered key precedes every
+                           chunk query), a (1, T) row derived from
+                           page_start, and the intra-chunk phase needs only
+                           a STATIC causal mask built in-kernel — no
+                           per-query mask ever hits HBM.
 
 Both reuse the (m, l, acc) online-softmax contract of
 paged_attention._stream_tile: init at the first tile, masked
 rescale-and-accumulate per tile, normalize in the last tile's epilogue
 (all-invalid rows yield 0 via the l = max(l, 1e-30) guard).
 
-Layout: q is folded to (BH, Cq*G, D) with row r = c*G + g, BH = B*Hkv.
+Layout: BH = B*Hkv. chunk_attention folds q to (BH, G, Cq, D);
+chunk_attention_paged to (BH, Cq*G, D) with row r = c*G + g.
 """
 from __future__ import annotations
 
@@ -55,8 +57,21 @@ def _accumulate(s, ok, v, m_ref, l_ref, acc_ref):
     m_ref[...] = m_new
 
 
+# (rows x BT) f32 logits per KV tile: 2 MiB keeps a tile's logits,
+# probabilities and masks inside the TPU's default 16 MiB scoped VMEM
+_TILE_ELEMS = 1 << 19
+
+
+def _kv_tile(bt: int, t: int, rows: int) -> int:
+    """KV tile length: ``bt``, shrunk in 128-lane steps for tall query
+    blocks (long chunks) so a (rows, BT) tile stays within
+    ``_TILE_ELEMS``."""
+    cap = max(128, _TILE_ELEMS // rows // 128 * 128)
+    return min(bt, t, cap)
+
+
 def _chunk_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref,
-                  acc_ref, *, bt, seq_t, cq, group):
+                  acc_ref, *, bt, seq_t, group):
     ti = pl.program_id(1)
 
     @pl.when(ti == 0)
@@ -65,20 +80,20 @@ def _chunk_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    cols = ti * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
-    inb = cols < seq_t                                       # (BT, 1)
+    rows = ti * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+    inb = rows < seq_t                                       # (BT, 1)
     k = jnp.where(inb, k_ref[0].astype(jnp.float32), 0.0)    # (BT, D)
     v = jnp.where(inb, v_ref[0].astype(jnp.float32), 0.0)
-    # per-query tile mask, expanded over the GQA group: row r = c*G + g
-    okq = (valid_ref[0] != 0) & inb[:, 0][None, :]           # (Cq, BT)
-    ok = jnp.broadcast_to(okq[:, None, :], (cq, group, bt)).reshape(
-        cq * group, bt)
-    q = q_ref[0].astype(jnp.float32)                         # (Cq*G, D)
-
-    scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    s = jnp.where(ok, s, NEG_INF)
-    _accumulate(s, ok, v, m_ref, l_ref, acc_ref)
+    cols = ti * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+    ok = (valid_ref[0] != 0) & (cols < seq_t)                # (Cq, BT)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(k.shape[-1], jnp.float32))
+    # one (Cq, D) query block per GQA group member: the per-query mask
+    # applies to each as is, never expanded over the group
+    for g in range(group):
+        q = q_ref[0, g].astype(jnp.float32)                  # (Cq, D)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        s = jnp.where(ok, s, NEG_INF)
+        _accumulate(s, ok, v, m_ref.at[g], l_ref.at[g], acc_ref.at[g])
 
     @pl.when(ti == pl.num_programs(1) - 1)
     def _finish():
@@ -96,39 +111,39 @@ def chunk_attention(q, k, v, valid, *, bt=512, interpret=False):
     b, cq, hq, d = q.shape
     h_kv, t = k.shape[1], k.shape[2]
     g = hq // h_kv
-    qg = q.reshape(b, cq, h_kv, g, d).transpose(0, 2, 1, 3, 4)
-    qg = qg.reshape(b * h_kv, cq * g, d)
+    # (BH, G, Cq, D): the group is a leading (untiled) dim of the block
+    qg = q.reshape(b, cq, h_kv, g, d).transpose(0, 2, 3, 1, 4)
+    qg = qg.reshape(b * h_kv, g, cq, d)
     kt = k.reshape(b * h_kv, t, d)
     vt = v.reshape(b * h_kv, t, d)
     vd = valid.reshape(b * h_kv, cq, t).astype(jnp.int32)
 
-    bt_ = min(bt, t)
+    bt_ = _kv_tile(bt, t, cq)
     nt = pl.cdiv(t, bt_)
     out = pl.pallas_call(
-        functools.partial(_chunk_kernel, bt=bt_, seq_t=t, cq=cq, group=g),
+        functools.partial(_chunk_kernel, bt=bt_, seq_t=t, group=g),
         grid=(b * h_kv, nt),
         in_specs=[
-            pl.BlockSpec((1, cq * g, d), lambda bh, ti: (bh, 0, 0)),
+            pl.BlockSpec((1, g, cq, d), lambda bh, ti: (bh, 0, 0, 0)),
             pl.BlockSpec((1, bt_, d), lambda bh, ti: (bh, ti, 0)),
             pl.BlockSpec((1, bt_, d), lambda bh, ti: (bh, ti, 0)),
             pl.BlockSpec((1, cq, bt_), lambda bh, ti: (bh, 0, ti)),
         ],
-        out_specs=pl.BlockSpec((1, cq * g, d), lambda bh, ti: (bh, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h_kv, cq * g, d), q.dtype),
+        out_specs=pl.BlockSpec((1, g, cq, d), lambda bh, ti: (bh, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * h_kv, g, cq, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((cq * g, 1), jnp.float32),
-            pltpu.VMEM((cq * g, 1), jnp.float32),
-            pltpu.VMEM((cq * g, d), jnp.float32),
+            pltpu.VMEM((g, cq, 1), jnp.float32),
+            pltpu.VMEM((g, cq, 1), jnp.float32),
+            pltpu.VMEM((g, cq, d), jnp.float32),
         ],
         interpret=interpret,
     )(qg, kt, vt, vd)
-    out = out.reshape(b, h_kv, cq, g, d).transpose(0, 2, 1, 3, 4)
+    out = out.reshape(b, h_kv, g, cq, d).transpose(0, 3, 1, 2, 4)
     return out.reshape(b, cq, hq, d)
 
 
-def _paged_kernel(q_ref, kp_ref, vp_ref, ps_ref, st_ref, kn_ref, vn_ref,
-                  o_ref, m_ref, l_ref, acc_ref, *, bpp, page, n_pages, npt,
-                  cq, group):
+def _paged_kernel(q_ref, kp_ref, vp_ref, ok_ref, kn_ref, vn_ref, o_ref,
+                  m_ref, l_ref, acc_ref, *, bt, seq_t, npt, cq, group):
     ti = pl.program_id(1)
 
     @pl.when(ti == 0)
@@ -139,22 +154,18 @@ def _paged_kernel(q_ref, kp_ref, vp_ref, ps_ref, st_ref, kn_ref, vn_ref,
 
     q = q_ref[0].astype(jnp.float32)                         # (Cq*G, D)
     scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
-    start = st_ref[0, 0]
 
     @pl.when(ti < npt)
     def _pages():
-        # fused gather: validity from page metadata, in-kernel. Every
-        # buffered key precedes every chunk query (pos < start), so the
-        # mask is per-KEY — no Cq axis.
-        ps = ps_ref[...].reshape(bpp, 1)                     # (BPP, 1)
-        pidx = ti * bpp + jax.lax.broadcasted_iota(
-            jnp.int32, (bpp, page), 0)
-        offs = jax.lax.broadcasted_iota(jnp.int32, (bpp, page), 1)
-        pos = ps + offs
-        ok2 = (pidx < n_pages) & (ps >= 0) & (pos < start)   # (BPP, P)
-        ok = ok2.reshape(1, bpp * page)
-        k = jnp.where(ok[0][:, None], kp_ref[0].astype(jnp.float32), 0.0)
-        v = jnp.where(ok[0][:, None], vp_ref[0].astype(jnp.float32), 0.0)
+        # fused gather: the pages stream straight from the paged buffer.
+        # Every buffered key precedes every chunk query (pos < start), so
+        # the mask is per-KEY — one (1, BT) lane row, no Cq axis.
+        rows = ti * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+        inb = rows < seq_t
+        cols = ti * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        ok = (cols < seq_t) & (ok_ref[0] != 0)               # (1, BT)
+        k = jnp.where(inb, kp_ref[0].astype(jnp.float32), 0.0)
+        v = jnp.where(inb, vp_ref[0].astype(jnp.float32), 0.0)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         s = jnp.where(ok, s, NEG_INF)
         _accumulate(s, ok, v, m_ref, l_ref, acc_ref)
@@ -165,10 +176,10 @@ def _paged_kernel(q_ref, kp_ref, vp_ref, ps_ref, st_ref, kn_ref, vn_ref,
         # row r = c*G + g iff j <= c.
         k = kn_ref[0].astype(jnp.float32)                    # (Cq, D)
         v = vn_ref[0].astype(jnp.float32)
-        rows_c = jax.lax.broadcasted_iota(
-            jnp.int32, (cq * group, cq), 0) // group
+        # (j <= r // G  <=>  j * G <= r, with no vector division)
+        rows = jax.lax.broadcasted_iota(jnp.int32, (cq * group, cq), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (cq * group, cq), 1)
-        ok = cols <= rows_c
+        ok = cols * group <= rows
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         s = jnp.where(ok, s, NEG_INF)
         _accumulate(s, ok, v, m_ref, l_ref, acc_ref)
@@ -199,30 +210,34 @@ def chunk_attention_paged(q, k_pages, v_pages, page_start, start, k_new,
     bh = b * hr
     qg = q.reshape(b, cq, hr, g, d).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(bh, cq * g, d)
-    kp = k_pages.reshape(bh, c * page, d)
-    vp = v_pages.reshape(bh, c * page, d)
-    ps = page_start.reshape(bh, c).astype(jnp.int32)
+    t = c * page
+    kp = k_pages.reshape(bh, t, d)
+    vp = v_pages.reshape(bh, t, d)
+    # per-key validity from page metadata: written pages, keys before
+    # the chunk. A (BH, 1, T) lane row — 1/Cq of a per-query mask.
+    ps = page_start.reshape(bh, c, 1).astype(jnp.int32)
+    pos = (ps + jnp.arange(page, dtype=jnp.int32)).reshape(bh, 1, t)
     st = jnp.repeat(jnp.asarray(start, jnp.int32).reshape(b), hr)
-    st = st.reshape(bh, 1)
+    written = jnp.broadcast_to(ps >= 0, (bh, c, page)).reshape(bh, 1, t)
+    ok = (written & (pos < st[:, None, None])).astype(jnp.int32)
     kn = k_new.transpose(0, 2, 1, 3).reshape(bh, cq, d)
     vn = v_new.transpose(0, 2, 1, 3).reshape(bh, cq, d)
 
-    bpp = max(1, min(bt // page, c))    # whole pages per KV tile
-    npt = pl.cdiv(c, bpp)
+    bt_ = _kv_tile(bt, t, cq * g)
+    npt = pl.cdiv(t, bt_)
     last = npt - 1
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, bpp=bpp, page=page, n_pages=c,
-                          npt=npt, cq=cq, group=g),
+        functools.partial(_paged_kernel, bt=bt_, seq_t=t, npt=npt, cq=cq,
+                          group=g),
         grid=(bh, npt + 1),
         in_specs=[
             pl.BlockSpec((1, cq * g, d), lambda bh_, ti: (bh_, 0, 0)),
-            pl.BlockSpec((1, bpp * page, d),
+            pl.BlockSpec((1, bt_, d),
                          lambda bh_, ti: (bh_, jnp.minimum(ti, last), 0)),
-            pl.BlockSpec((1, bpp * page, d),
+            pl.BlockSpec((1, bt_, d),
                          lambda bh_, ti: (bh_, jnp.minimum(ti, last), 0)),
-            pl.BlockSpec((1, bpp),
-                         lambda bh_, ti: (bh_, jnp.minimum(ti, last))),
-            pl.BlockSpec((1, 1), lambda bh_, ti: (bh_, 0)),
+            pl.BlockSpec((1, 1, bt_),
+                         lambda bh_, ti: (bh_, 0, jnp.minimum(ti, last))),
             pl.BlockSpec((1, cq, d), lambda bh_, ti: (bh_, 0, 0)),
             pl.BlockSpec((1, cq, d), lambda bh_, ti: (bh_, 0, 0)),
         ],
@@ -234,6 +249,6 @@ def chunk_attention_paged(q, k_pages, v_pages, page_start, start, k_new,
             pltpu.VMEM((cq * g, d), jnp.float32),
         ],
         interpret=interpret,
-    )(qg, kp, vp, ps, st, kn, vn)
+    )(qg, kp, vp, ok, kn, vn)
     out = out.reshape(b, hr, cq, g, d).transpose(0, 2, 1, 3, 4)
     return out.reshape(b, cq, hq, d)

@@ -4,24 +4,25 @@
   "ref"    — pure-jnp oracle (kernels/ref.py). Used for CPU tests and for
              the multi-pod dry-run (native HLO is what GSPMD partitions and
              what cost_analysis models).
-  "pallas" — Pallas TPU kernel (pl.pallas_call). On non-TPU backends the
-             wrappers run the kernel in interpret mode so correctness is
-             testable everywhere. ("kernel" is accepted as a legacy alias.)
+  "pallas" — Pallas TPU kernel (pl.pallas_call), compiled by Mosaic on
+             a TPU. On the CPU backend (tests) the wrappers run the kernel
+             in interpret mode; any other backend raises. The choice is
+             made at call time (``interpret_mode``), never at import, so
+             importing this module does not initialise a backend.
+             ("kernel" is accepted as a legacy alias.)
 
 Unknown ``impl`` strings raise ValueError (they used to fall through to
 the kernel path silently). See docs/kernels.md for the kernel catalog.
 """
 from __future__ import annotations
 
+import functools
 import warnings
-from functools import partial
 
 import jax
-import jax.numpy as jnp
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.kernels import ref as _ref
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 VALID_IMPLS = ("ref", "pallas")
 _ALIASES = {"kernel": "pallas"}
@@ -53,22 +54,53 @@ def resolve_impl(impl: str) -> str:
     return impl
 
 
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run interpreted on the current backend:
+    compiled on "tpu", interpreted on "cpu"; anything else raises rather
+    than silently interpreting."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"impl='pallas' needs a TPU (compiled) or the CPU backend "
+        f"(interpret mode); the default backend is {backend!r}")
+
+
+def _pallas(kernel, *args, **static):
+    """Call a Pallas kernel, compiled or interpreted per backend.
+
+    GSPMD cannot partition a Mosaic kernel, so under an ambient mesh
+    (``jax.set_mesh``) whose automatic axes span more than one device
+    the kernel runs inside ``shard_map``, replicated over all of them:
+    every device computes it whole, as GSPMD would for replicated
+    operands. Inside a shard_map body (manual axes) it runs as is."""
+    fn = functools.partial(kernel, interpret=interpret_mode(), **static)
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == AxisType.Auto}
+    if any(mesh.shape[a] > 1 for a in auto):
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                           axis_names=auto, check_vma=False)
+    return fn(*args)
+
+
 def flash_attention(q, k, v, *, causal=True, window=0, sink=0, q_offset=0,
                     impl="ref"):
     if resolve_impl(impl) == "ref":
         return _ref.flash_attention_ref(
             q, k, v, causal=causal, window=window, sink=sink, q_offset=q_offset)
     from repro.kernels import flash_attention as fk
-    return fk.flash_attention(
-        q, k, v, causal=causal, window=window, sink=sink, q_offset=q_offset,
-        interpret=_INTERPRET)
+    return _pallas(fk.flash_attention, q, k, v, causal=causal,
+                   window=window, sink=sink, q_offset=q_offset)
 
 
 def paged_attention(q, k, v, valid, *, impl="ref"):
     if resolve_impl(impl) == "ref":
         return _ref.paged_attention_ref(q, k, v, valid)
     from repro.kernels import paged_attention as pk
-    return pk.paged_attention(q, k, v, valid, interpret=_INTERPRET)
+    return _pallas(pk.paged_attention, q, k, v, valid)
 
 
 def chunk_attention(q, k, v, valid, *, impl="ref"):
@@ -80,7 +112,7 @@ def chunk_attention(q, k, v, valid, *, impl="ref"):
     if resolve_impl(impl) == "ref":
         return _ref.chunk_attention_ref(q, k, v, valid)
     from repro.kernels import chunk_attention as ck
-    return ck.chunk_attention(q, k, v, valid, interpret=_INTERPRET)
+    return _pallas(ck.chunk_attention, q, k, v, valid)
 
 
 def chunk_attention_paged(q, k_pages, v_pages, page_start, start, k_new,
@@ -99,9 +131,8 @@ def chunk_attention_paged(q, k_pages, v_pages, page_start, start, k_new,
         return _ref.chunk_attention_paged_ref(
             q, k_pages, v_pages, page_start, start, k_new, v_new)
     from repro.kernels import chunk_attention as ck
-    return ck.chunk_attention_paged(
-        q, k_pages, v_pages, page_start, start, k_new, v_new,
-        interpret=_INTERPRET)
+    return _pallas(ck.chunk_attention_paged, q, k_pages, v_pages,
+                   page_start, start, k_new, v_new)
 
 
 def paged_attention_partial(q, k, v, valid, *, impl="ref"):
@@ -110,7 +141,7 @@ def paged_attention_partial(q, k, v, valid, *, impl="ref"):
     if resolve_impl(impl) == "ref":
         return _ref.paged_attention_partial_ref(q, k, v, valid)
     from repro.kernels import paged_attention as pk
-    return pk.paged_attention_partial(q, k, v, valid, interpret=_INTERPRET)
+    return _pallas(pk.paged_attention_partial, q, k, v, valid)
 
 
 def combine_partials(m, l, o, *, axis=0, impl="ref"):
@@ -126,11 +157,11 @@ def combine_partials(m, l, o, *, axis=0, impl="ref"):
         raise ValueError(f"pallas combine_partials requires axis=0, "
                          f"got axis={axis}")
     from repro.kernels import paged_attention as pk
-    return pk.combine_partials(m, l, o, interpret=_INTERPRET)
+    return _pallas(pk.combine_partials, m, l, o)
 
 
 def page_score(q, tau_min, tau_max, *, impl="ref"):
     if resolve_impl(impl) == "ref":
         return _ref.page_score_ref(q, tau_min, tau_max)
     from repro.kernels import page_score as sk
-    return sk.page_score(q, tau_min, tau_max, interpret=_INTERPRET)
+    return _pallas(sk.page_score, q, tau_min, tau_max)

@@ -9,7 +9,7 @@ matmuls with a sign-split q). The metadata tensors stream through VMEM in
 (BC, D) tiles — the paper's memory-die min/max metadata units,
 re-expressed for the MXU.
 
-Layout: q (BH, G, D); tau (BH, C, D) -> scores (BH, C), BH = B * Hkv.
+Layout: q (BH, G, D); tau (BH, C, D) -> scores (BH, 1, C), BH = B * Hkv.
 """
 from __future__ import annotations
 
@@ -26,9 +26,9 @@ def _kernel(q_ref, tmin_ref, tmax_ref, o_ref):
     tmax = tmax_ref[0].astype(jnp.float32)    # (BC, D)
     qp = jnp.maximum(q, 0.0)
     qn = jnp.minimum(q, 0.0)
-    hi = jnp.dot(tmax, qp.T, preferred_element_type=jnp.float32)  # (BC, G)
-    lo = jnp.dot(tmin, qn.T, preferred_element_type=jnp.float32)
-    o_ref[0] = (hi + lo).sum(axis=-1)
+    hi = jnp.dot(qp, tmax.T, preferred_element_type=jnp.float32)  # (G, BC)
+    lo = jnp.dot(qn, tmin.T, preferred_element_type=jnp.float32)
+    o_ref[0] = (hi + lo).sum(axis=0, keepdims=True)              # (1, BC)
 
 
 @functools.partial(jax.jit, static_argnames=("bc", "interpret"))
@@ -51,8 +51,9 @@ def page_score(q, tau_min, tau_max, *, bc=512, interpret=False):
             pl.BlockSpec((1, bc_, d), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, bc_, d), lambda bh, ci: (bh, ci, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bc_), lambda bh, ci: (bh, ci)),
-        out_shape=jax.ShapeDtypeStruct((b * h_kv, c), jnp.float32),
+        # (BH, 1, C): a lane row of scores per program, TPU-tileable
+        out_specs=pl.BlockSpec((1, 1, bc_), lambda bh, ci: (bh, 0, ci)),
+        out_shape=jax.ShapeDtypeStruct((b * h_kv, 1, c), jnp.float32),
         interpret=interpret,
     )(qg, tn, tx)
     return out.reshape(b, h_kv, c)

@@ -20,8 +20,10 @@ Three entry points (see docs/kernels.md for the full catalog):
                              sum/divide over the shard axis in one kernel
                              (the paper's cross-bank softmax merge).
 
-Layout: q (BH, G, D); kv (BH, T, D); valid (BH, T) -> out (BH, G, D),
-where BH = B * Hkv.
+Layout: q (BH, G, D); kv (BH, T, D); valid (BH, 1, T) -> out (BH, G, D),
+where BH = B * Hkv. Every block keeps its last two dims TPU-tileable
+(equal to the array's, or multiples of (8, 128)): masks are lane rows
+behind a unit sublane dim, and m/l partials carry a trailing unit dim.
 """
 from __future__ import annotations
 
@@ -53,17 +55,19 @@ def _stream_tile(q_ref, k_ref, v_ref, valid_ref, m_ref, l_ref, acc_ref, *,
     inb = rows < seq_t
     k = jnp.where(inb, k_ref[0].astype(jnp.float32), 0.0)   # (BT, D)
     v = jnp.where(inb, v_ref[0].astype(jnp.float32), 0.0)   # (BT, D)
-    ok = inb[:, 0] & (valid_ref[0] != 0)                     # (BT,)
+    # key validity stays a lane row: (1, BT) broadcasts over the G rows
+    cols = ti * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+    ok = (cols < seq_t) & (valid_ref[0] != 0)                # (1, BT)
     q = q_ref[0].astype(jnp.float32)                         # (G, D)
 
     scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (G, BT)
-    s = jnp.where(ok[None, :], s, NEG_INF)
+    s = jnp.where(ok, s, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
-    p = jnp.where(ok[None, :], p, 0.0)  # all-masked tile: exp(-inf - -inf)=1
+    p = jnp.where(ok, p, 0.0)  # all-masked tile: exp(-inf - -inf)=1
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * corr + jnp.dot(
@@ -82,7 +86,9 @@ def _stream_call(kernel, q, k, v, valid, *, bt, interpret, out_specs,
     qg = q.reshape(b * h_kv, g, d)
     kt = k.reshape(b * h_kv, t, d)
     vt = v.reshape(b * h_kv, t, d)
-    vd = valid.reshape(b * h_kv, t).astype(jnp.int32)
+    # (BH, 1, T): the mask block's last two dims are (1, bt), which the
+    # TPU tiling accepts (1 == the array's dim, bt == T or a 128 multiple)
+    vd = valid.reshape(b * h_kv, 1, t).astype(jnp.int32)
 
     bt_ = min(bt, t)
     nt = pl.cdiv(t, bt_)
@@ -93,7 +99,7 @@ def _stream_call(kernel, q, k, v, valid, *, bt, interpret, out_specs,
             pl.BlockSpec((1, g, d), lambda bh, ti: (bh, 0, 0)),
             pl.BlockSpec((1, bt_, d), lambda bh, ti: (bh, ti, 0)),
             pl.BlockSpec((1, bt_, d), lambda bh, ti: (bh, ti, 0)),
-            pl.BlockSpec((1, bt_), lambda bh, ti: (bh, ti)),
+            pl.BlockSpec((1, 1, bt_), lambda bh, ti: (bh, 0, ti)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -143,8 +149,8 @@ def _partial_kernel(q_ref, k_ref, v_ref, valid_ref, m_out, l_out, o_out,
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _finish():
-        m_out[0] = m_ref[...][:, 0]
-        l_out[0] = l_ref[...][:, 0]
+        m_out[0] = m_ref[...]
+        l_out[0] = l_ref[...]
         o_out[0] = acc_ref[...]
 
 
@@ -163,32 +169,37 @@ def paged_attention_partial(q, k, v, valid, *, bt=512, interpret=False):
     g = hq // h_kv
     m, l, o = _stream_call(
         _partial_kernel, q, k, v, valid, bt=bt, interpret=interpret,
+        # m/l carry a trailing unit dim: (BH, G, 1) blocks of (1, G, 1)
+        # store the (G, 1) scratch as is, with no rank-changing cast
         out_specs=[
-            pl.BlockSpec((1, g), lambda bh, ti: (bh, 0)),
-            pl.BlockSpec((1, g), lambda bh, ti: (bh, 0)),
+            pl.BlockSpec((1, g, 1), lambda bh, ti: (bh, 0, 0)),
+            pl.BlockSpec((1, g, 1), lambda bh, ti: (bh, 0, 0)),
             pl.BlockSpec((1, g, d), lambda bh, ti: (bh, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h_kv, g), jnp.float32),
-            jax.ShapeDtypeStruct((b * h_kv, g), jnp.float32),
+            jax.ShapeDtypeStruct((b * h_kv, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b * h_kv, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b * h_kv, g, d), jnp.float32),
         ])
     return m.reshape(b, hq), l.reshape(b, hq), o.reshape(b, hq, d)
 
 
 def _combine_kernel(m_ref, l_ref, o_ref, out_ref, *, br, n_rows):
-    """Fused cross-bank epilogue: global max, rescale, sum, divide."""
+    """Fused cross-bank epilogue: global max, rescale, sum, divide.
+
+    m/l arrive as (N, BR, 1) so every reduction runs over the leading
+    shard axis and every broadcast is along lanes — no rank change."""
     ri = pl.program_id(0)
-    rows = ri * br + jax.lax.broadcasted_iota(jnp.int32, (1, br), 1)
-    inb = rows < n_rows                                      # (1, BR)
-    m = jnp.where(inb, m_ref[...], NEG_INF)                  # (N, BR)
+    rows = ri * br + jax.lax.broadcasted_iota(jnp.int32, (1, br, 1), 1)
+    inb = rows < n_rows                                      # (1, BR, 1)
+    m = jnp.where(inb, m_ref[...], NEG_INF)                  # (N, BR, 1)
     l = jnp.where(inb, l_ref[...], 0.0)
-    o = jnp.where(inb[..., None], o_ref[...], 0.0)           # (N, BR, D)
-    m_g = jnp.max(m, axis=0)                                 # (BR,)
-    corr = jnp.exp(m - m_g[None, :])                         # (N, BR)
-    l_g = jnp.sum(l * corr, axis=0)
-    o_g = jnp.sum(o * corr[..., None], axis=0)               # (BR, D)
-    out_ref[...] = o_g / jnp.maximum(l_g, 1e-30)[:, None]
+    o = jnp.where(inb, o_ref[...], 0.0)                      # (N, BR, D)
+    m_g = jnp.max(m, axis=0)                                 # (BR, 1)
+    corr = jnp.exp(m - m_g)                                  # (N, BR, 1)
+    l_g = jnp.sum(l * corr, axis=0)                          # (BR, 1)
+    o_g = jnp.sum(o * corr, axis=0)                          # (BR, D)
+    out_ref[...] = o_g / jnp.maximum(l_g, 1e-30)
 
 
 @functools.partial(jax.jit, static_argnames=("br", "interpret"))
@@ -202,8 +213,8 @@ def combine_partials(m, l, o, *, br=128, interpret=False):
     n, b_, hq = m.shape
     d = o.shape[-1]
     r = b_ * hq
-    mr = m.reshape(n, r)
-    lr = l.reshape(n, r)
+    mr = m.reshape(n, r, 1)
+    lr = l.reshape(n, r, 1)
     orr = o.reshape(n, r, d)
 
     br_ = min(br, r)
@@ -212,8 +223,8 @@ def combine_partials(m, l, o, *, br=128, interpret=False):
         functools.partial(_combine_kernel, br=br_, n_rows=r),
         grid=(nr,),
         in_specs=[
-            pl.BlockSpec((n, br_), lambda ri: (0, ri)),
-            pl.BlockSpec((n, br_), lambda ri: (0, ri)),
+            pl.BlockSpec((n, br_, 1), lambda ri: (0, ri, 0)),
+            pl.BlockSpec((n, br_, 1), lambda ri: (0, ri, 0)),
             pl.BlockSpec((n, br_, d), lambda ri: (0, ri, 0)),
         ],
         out_specs=pl.BlockSpec((br_, d), lambda ri: (ri, 0)),

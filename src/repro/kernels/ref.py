@@ -286,11 +286,16 @@ def chunk_attention_paged_ref(
     causal = jnp.arange(cq)[:, None] >= jnp.arange(cq)[None, :]
     ln = jnp.where(causal[None, None, None], ln, NEG_INF)
     probs = jax.nn.softmax(jnp.concatenate([lc, ln], axis=-1), axis=-1)
-    out = jnp.einsum("bhgct,bhtd->bchgd", probs[..., : c * p].astype(
+    # contract into (b, h, g, c, d) and transpose after: an einsum that
+    # emits "bchgd" directly puts vb first with its contracting dim
+    # minor-but-one, a bf16 x bf16 -> f32 dot XLA:CPU cannot execute
+    out = jnp.einsum("bhgct,bhtd->bhgcd", probs[..., : c * p].astype(
         vb.dtype), vb, preferred_element_type=jnp.float32)
     out = out + jnp.einsum(
-        "bhgcj,bjhd->bchgd", probs[..., c * p:].astype(v_new.dtype),
-        v_new.astype(vb.dtype), preferred_element_type=jnp.float32)
+        "bhgcj,bhjd->bhgcd", probs[..., c * p:].astype(v_new.dtype),
+        v_new.astype(vb.dtype).transpose(0, 2, 1, 3),
+        preferred_element_type=jnp.float32)
+    out = out.transpose(0, 3, 1, 2, 4)                       # (b,c,h,g,d)
     return out.reshape(b, cq, hq, d).astype(q.dtype)
 
 

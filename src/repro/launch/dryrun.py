@@ -89,7 +89,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
     set_sp_residual(shape.kind != "train"
                     or cfg.num_heads % mesh.shape["model"] != 0)
     hints_on = not (shape.kind == "train" and cfg.moe.enabled)
-    with mesh, sharding_hints(hints_on):
+    with jax.set_mesh(mesh), sharding_hints(hints_on):
         if shape.kind == "train":
             params = S.param_specs(cfg, dtype=jnp.float32)
             batch = S.train_specs(cfg, shape)

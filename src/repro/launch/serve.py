@@ -29,7 +29,7 @@ striping (paper Fig 7b); ``--admission balanced`` adds the
 balance-aware admission order (sched/balance.admission_score) for any
 page-sharding layout.
 ``--attn-impl pallas`` swaps the attention bodies for the Pallas kernels
-(kernels/ops.py dispatch; interpret mode off-TPU) — including the
+(kernels/ops.py dispatch; interpret mode on CPU) — including the
 partial-attention + fused-combine pair inside the coplace_shmap decode.
 The impl is fixed at engine construction, never switched per step.
 ``--rebalance retire|interval`` arms live slot migration
@@ -56,6 +56,7 @@ CPU demo (reduced config):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -66,6 +67,7 @@ from repro.configs import get_arch, reduced
 from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
 from repro.runtime import serve as serve_rt
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def generate(cfg, params, prompts, *, gen: int, capacity: int,
@@ -94,22 +96,25 @@ def generate(cfg, params, prompts, *, gen: int, capacity: int,
         dec_reuse = jax.jit(serve_rt.make_decode_step(cfg, scfg,
                                                       do_select=False))
 
-    t0 = time.time()
-    logits, state = prefill(params, prompts)
-    logits.block_until_ready()
-    t_prefill = time.time() - t0
+    # the shard_map layouts read the ambient mesh while tracing
+    with (jax.set_mesh(mesh) if mesh is not None
+          else contextlib.nullcontext()):
+        t0 = time.time()
+        logits, state = prefill(params, prompts)
+        logits.block_until_ready()
+        t_prefill = time.time() - t0
 
-    w = max(cfg.h2eal.share_window, 1)
-    outs = []
-    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    t0 = time.time()
-    for i in range(gen):
-        outs.append(tok)
-        fn = dec_sel if (i % w == 0) else dec_reuse
-        logits, state = fn(params, state, tok)
+        w = max(cfg.h2eal.share_window, 1)
+        outs = []
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    jax.block_until_ready(logits)
-    t_decode = time.time() - t0
+        t0 = time.time()
+        for i in range(gen):
+            outs.append(tok)
+            fn = dec_sel if (i % w == 0) else dec_reuse
+            logits, state = fn(params, state, tok)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        jax.block_until_ready(logits)
+        t_decode = time.time() - t0
     stats = {
         "prefill_s": t_prefill,
         "decode_s": t_decode,
@@ -145,7 +150,7 @@ def run_ragged(cfg, params, requests, *, max_batch: int, capacity: int,
     runs the sharded partial-attention decode; "interleave" stripes
     within-page tokens over the 'data' axis under GSPMD);
     ``attn_impl="pallas"`` swaps the decode body for the Pallas kernels
-    (interpret mode off-TPU) — fixed at engine construction, never per
+    (interpret mode on CPU) — fixed at engine construction, never per
     step. ``prefill_chunk=N`` switches admission from prefill-then-pack
     to chunked slot-resident prefill (≤ N prompt tokens per engine step,
     interleaved with decode — docs/serving.md). ``rebalance`` arms the
@@ -316,9 +321,10 @@ def main(argv=None):
     ap.add_argument("--attn-impl", choices=["ref", "pallas"], default="ref",
                     help="attention kernel impl (kernels/ops.py): ref = "
                          "pure-jnp oracle, pallas = Pallas kernels "
-                         "(interpret mode off-TPU). Fixed at engine "
+                         "(interpret mode on CPU). Fixed at engine "
                          "construction; see docs/serving.md")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

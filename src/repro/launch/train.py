@@ -32,6 +32,7 @@ from repro.models import model as M
 from repro.optim import adamw
 from repro.runtime import sharding as shardlib
 from repro.runtime import train as train_rt
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -53,6 +54,7 @@ def main(argv=None):
                     help="raise after N steps (fault-tolerance test)")
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

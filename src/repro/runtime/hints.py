@@ -43,46 +43,22 @@ def sharding_hints(on: bool = True):
 
 
 def _axes():
-    # the abstract mesh is only set in explicit-sharding mode (jax >= 0.5;
-    # None under the pinned 0.4.x); inside a plain `with mesh:` context the
-    # physical mesh lives in thread resources (constraints with bare
-    # PartitionSpecs resolve against it)
-    from repro.runtime.compat import get_abstract_mesh
-
-    am = get_abstract_mesh()
-    if am is not None and not am.empty:
-        return am.axis_names
-    try:
-        from jax._src import mesh as _mesh_lib
-
-        pm = _mesh_lib.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm.axis_names
-    except Exception:
-        pass
-    return None
+    """Axis names of the ambient mesh (``jax.set_mesh``), or None."""
+    mesh = current_mesh()
+    return None if mesh is None else mesh.axis_names
 
 
 def current_mesh():
-    """The ambient physical mesh, or None."""
-    try:
-        from jax._src import mesh as _mesh_lib
-
-        pm = _mesh_lib.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except Exception:
-        pass
-    return None
+    """The ambient mesh set by ``jax.set_mesh`` (an AbstractMesh during
+    tracing), or None when no mesh is set."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def constrain(x, spec: P):
     if not enabled():
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def batch_axes_spec():

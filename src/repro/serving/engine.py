@@ -375,7 +375,7 @@ class Engine:
                   packed admission).
     impl        : attention kernel implementation, ``"ref"`` (pure-jnp
                   oracle) or ``"pallas"`` (Pallas kernels; interpret mode
-                  off-TPU). Validated and BAKED INTO the compiled step
+                  on CPU). Validated and BAKED INTO the compiled step
                   functions here at construction — impl switching never
                   happens per step, so the zero-recompile invariant is
                   unaffected (docs/serving.md). Exposed as ``--attn-impl``
@@ -634,6 +634,10 @@ class Engine:
             ss = self.plan.state_shardings(cfg, self.batch.serve,
                                            batch_size=max_batch)
             self.batch.serve = jax.device_put(self.batch.serve, ss)
+            # weights replicated on the mesh once, here: left on the
+            # default device, every sharded step would re-broadcast them
+            self.params = jax.device_put(params,
+                                         shardlib.replicated(self.mesh))
             dec_shard = {"out_shardings":
                          shardlib.serve_step_out_shardings(self.mesh, ss)}
             reset_shard = {"out_shardings": ss}
@@ -794,7 +798,9 @@ class Engine:
         """Ambient-mesh context for jitted calls: the shard_map co-placement
         path discovers the mesh at trace time (runtime/hints.current_mesh),
         so every prefill/decode/pack dispatch runs inside it."""
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
 
     def _init_batch_state(self, max_batch: int) -> BatchState:
         """All-free batched state. Cache contents are irrelevant until a
@@ -985,31 +991,38 @@ class Engine:
     def _tier_select(self, need: np.ndarray, need_dev, act_dev):
         """Tiered select step: dispatch (non-donated), read back the
         metadata-only selection, and — if any selected page is cold —
-        fill it into the PRESERVED input state and replay the step.
-        Selection depends only on tau metadata + page_start + q (never
-        page contents), so the replayed selection is identical and the
-        replayed attention is exactly the all-resident step: the miss is
-        served late, never skipped."""
+        fill it into the PRESERVED input state and replay the step,
+        until a pass selects only resident pages. A layer's selection
+        depends on tau metadata + page_start + its q, never on page
+        contents, but its q depends on the earlier layers' attention:
+        a pass that read a cold page can select differently in deeper
+        layers, so one replay is not always enough. The clean pass is
+        exactly the all-resident step: misses are served late, never
+        skipped, and its selection is what the refresh plan keeps
+        resident."""
         b = self.batch
-        logits, serve2 = self._dec_sel(self.params, b.serve, self._tok,
-                                       act_dev, need_dev)
-        self.stats.dispatches += 1
-        sel_by, hot_by = self._tier_digest(serve2, need)
-        miss_work = []
-        for slot in np.nonzero(need)[0]:
-            slot = int(slot)
-            missing = self._tier.missing(slot, sel_by[slot])
-            self.stats.tier_hits += len(sel_by[slot]) - len(missing)
-            self.stats.tier_misses += len(missing)
-            if missing:
-                miss_work.append((slot, missing))
-        if miss_work:
+        first = True
+        while True:
+            logits, serve2 = self._dec_sel(self.params, b.serve, self._tok,
+                                           act_dev, need_dev)
+            self.stats.dispatches += 1
+            sel_by, hot_by = self._tier_digest(serve2, need)
+            miss_work = []
+            for slot in np.nonzero(need)[0]:
+                slot = int(slot)
+                missing = self._tier.missing(slot, sel_by[slot])
+                if first:
+                    self.stats.tier_hits += (len(sel_by[slot])
+                                             - len(missing))
+                self.stats.tier_misses += len(missing)
+                if missing:
+                    miss_work.append((slot, missing))
+            if not miss_work:
+                break
+            first = False
             # every missed slot's repair rides ONE batched fill (PR 10)
             b.serve = self._tier_fill_work(b.serve, miss_work,
                                            prefetch=False)
-            logits, serve2 = self._dec_sel(self.params, b.serve,
-                                           self._tok, act_dev, need_dev)
-            self.stats.dispatches += 1
         self._tier_plan = (need.copy(), sel_by, hot_by)
         return logits, serve2
 

@@ -17,9 +17,10 @@ from repro.core.hybrid_attention import (AttnSpec, init_decode_state,
                                          decode_attention_coplace)
 from repro.configs.base import H2ealConfig
 from repro.runtime.hints import sharding_hints
-from repro.runtime.compat import make_mesh
+from jax.sharding import AxisType
 
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 B, Hq, Hkv, D = 2, 4, 2, 32
 S, P_, sink, local = 96, 8, 2, 16
 h2 = H2ealConfig(sink=sink, local=local, page_size=P_, select_budget=32,
@@ -33,7 +34,7 @@ pg_s, st_s = init_decode_state(spec, k, v, S, capacity=128)
 pg_c, st_c = init_decode_state(spec, k, v, S, capacity=128,
                                interleave_shards=4)
 L = jnp.int32(S)
-with mesh, sharding_hints(True):
+with jax.set_mesh(mesh), sharding_hints(True):
     f_std = jax.jit(lambda q, kn, vn, pg, st, l, s: decode_attention(
         spec, q, kn, vn, pg, st, l, do_select=s), static_argnums=(6,))
     f_cop = jax.jit(lambda q, kn, vn, pg, st, l, s: decode_attention_coplace(

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.page_score import page_score

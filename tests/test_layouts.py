@@ -152,14 +152,16 @@ def test_plan_mesh_and_capacity(model):
 def test_plan_validates_mesh_axes(model):
     """A layout whose mesh requirements aren't met fails at plan/Engine
     construction time, not at the first decode step."""
-    from repro.runtime.compat import make_mesh
+    from jax.sharding import AxisType
 
     cfg, params = model
     n = len(jax.devices())
-    no_data = make_mesh((n,), ("model",))
+    no_data = jax.make_mesh((n,), ("model",),
+                             axis_types=(AxisType.Auto,))
     with pytest.raises(ValueError, match="'data'"):
         layoutlib.get_layout("interleave").plan(cfg, no_data)
-    no_model = make_mesh((n,), ("data",))
+    no_model = jax.make_mesh((n,), ("data",),
+                              axis_types=(AxisType.Auto,))
     with pytest.raises(ValueError, match="'model'"):
         layoutlib.get_layout("coplace_shmap").plan(cfg, no_model)
     with pytest.raises(ValueError, match="'data'"):
@@ -179,10 +181,11 @@ def test_engine_resolves_layout_through_registry(model):
 
 def test_state_shardings_resolve_through_registry(model):
     from repro.runtime import sharding as shardlib
-    from repro.runtime.compat import make_mesh
+    from jax.sharding import AxisType
 
     cfg, _ = model
-    mesh = make_mesh((1, len(jax.devices())), ("data", "model"))
+    mesh = jax.make_mesh((1, len(jax.devices())), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with pytest.raises(ValueError, match="registered layouts"):
         shardlib.state_shardings(cfg, mesh, {"x": jnp.zeros((4, 4))},
                                  layout="bogus")

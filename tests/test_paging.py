@@ -7,7 +7,7 @@ union covers every in-context token exactly once.
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import paging
 

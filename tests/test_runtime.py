@@ -27,10 +27,11 @@ import jax, jax.numpy as jnp
 from repro.configs import get_arch, reduced
 from repro.models import model as M
 from repro.runtime import sharding as shardlib, serve as serve_rt
-from repro.runtime.compat import make_mesh
+from jax.sharding import AxisType
 from repro.launch import specs as S
 
-mesh = make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
 for name in ("smollm-360m", "qwen3-moe-235b-a22b", "zamba2-2.7b",
              "xlstm-125m"):
     cfg = reduced(get_arch(name))

@@ -11,7 +11,7 @@ never WHICH tokens:
      to the non-speculative engine for k in {1,2,4,8} across packed AND
      chunked admission amid slot churn; stochastic traces are invariant
      to slot assignment, admission order, and spec_tokens (property
-     test over temperature/top_p/seed via the hypothesis shim).
+     test over temperature/top_p/seed via hypothesis).
   3. Forced extremes via DraftProvider test doubles — all-reject
      (ConstantDraft) degenerates exactly to the baseline one-token
      step; all-accept (ReplayDraft + share_window >= k) emits k tokens
@@ -19,21 +19,18 @@ never WHICH tokens:
      steps_per_s vs tokens_per_s split.
 """
 import dataclasses
-import sys
-import os
 
 import jax
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from _hypothesis_compat import given, settings, st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
-from repro.configs import get_arch, reduced  # noqa: E402
-from repro.models import model as M  # noqa: E402
-from repro.serving import Engine, Request  # noqa: E402
-from repro.serving import sampling as samplib  # noqa: E402
-from repro.serving.draft import (ConstantDraft, NgramDraft,  # noqa: E402
+from repro.configs import get_arch, reduced
+from repro.models import model as M
+from repro.serving import Engine, Request
+from repro.serving import sampling as samplib
+from repro.serving.draft import (ConstantDraft, NgramDraft,
                                  ReplayDraft, resolve_draft)
 
 CAP = 64

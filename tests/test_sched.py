@@ -1,6 +1,6 @@
 """Scheduler properties: mapping (cases a/b/c), tiling, load balance."""
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.base import H2ealConfig
 from repro.sched import (

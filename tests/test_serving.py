@@ -299,7 +299,7 @@ import jax.tree_util as jtu
 from jax.sharding import PartitionSpec as P
 from repro.configs import get_arch, reduced
 from repro.models import model as M
-from repro.runtime.compat import make_mesh
+from jax.sharding import AxisType
 from tests.test_serving import CAP, _mixed_workload
 from repro.serving import Engine
 
@@ -310,7 +310,8 @@ eng0 = Engine(cfg, params, max_batch=2, capacity=CAP,
 c0 = eng0.run(_mixed_workload(cfg))
 # pages -> 'model' AND within-page tokens -> 'data': max_batch=2 cannot
 # consume data=4, so the pages leaves genuinely stripe within-page tokens
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
 eng1 = Engine(cfg, params, max_batch=2, capacity=CAP,
               prompt_buckets=[16, 24], layout="interleave", mesh=mesh,
               admission="balanced")
@@ -436,12 +437,12 @@ def test_chunked_prefill_matches_packed_with_churn(model):
 
 
 def test_chunked_prefill_property_chunk_x_prompt(model):
-    """Hypothesis-compat property: for any chunk size and prompt length,
+    """Hypothesis property: for any chunk size and prompt length,
     feeding the prompt through M.prefill_chunk (against a reset slot of
     the batched state) reproduces the single-shot M.prefill: same greedy
     first token, logits to float tolerance, and identical KV caches up
     to reassociation-level float error."""
-    from tests._hypothesis_compat import given, settings, st
+    from hypothesis import given, settings, strategies as st
 
     cfg, params = model
     from repro.runtime import serve as serve_rt

@@ -23,7 +23,7 @@ import pytest
 from repro.configs import get_arch, reduced
 from repro.models import model as M
 from repro.serving import Engine, Request
-from tests._hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 CAP = 128          # 16 pages of 8 -- enough table for real spill traffic
 
