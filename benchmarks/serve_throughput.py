@@ -299,8 +299,13 @@ def run_latency(cfg, params, *, requests, max_batch, capacity, buckets,
     pending = list(zip(arrive, reqs))
 
     t0 = time.time()
-    times = [t0]                 # times[k] = wall clock after engine step k
+    times = [t0]                 # wall clock after each dispatching poll
     submit_t = {}
+    # per uid: index into ``times`` of its admission, first token, and
+    # each later token, read from Engine.batch around every poll
+    admit_at, first_at, tok_at = {}, {}, {}
+    b = eng.batch
+    uid0, len0, pref0 = b.uid.copy(), b.lengths.copy(), b.prefilling.copy()
     while pending or eng.busy():
         step_no = eng.stats.engine_steps
         while pending and pending[0][0] <= step_no:
@@ -315,20 +320,34 @@ def run_latency(cfg, params, *, requests, max_batch, capacity, buckets,
             eng.submit(r)
         if eng.poll():
             eng.sync()
-            times.append(time.time())  # times[k] = wall after engine step k
+            times.append(time.time())
+            k = len(times) - 1
+            for s in range(b.max_batch):
+                before, after = int(uid0[s]), int(b.uid[s])
+                if after >= 0 and after != before:
+                    admit_at[after] = k
+                    if not b.prefilling[s]:   # prompt done within the poll
+                        first_at[after] = k
+                elif before >= 0:             # same occupant (-1: retired)
+                    if pref0[s] and not b.prefilling[s]:
+                        first_at[before] = k
+                    elif not pref0[s]:
+                        tok_at.setdefault(before, []).extend(
+                            [k] * int(b.lengths[s] - len0[s]))
+            uid0, len0 = b.uid.copy(), b.lengths.copy()
+            pref0 = b.prefilling.copy()
     eng.finalize()
 
     ttft, itl = [], []
     for comp in eng.completions.values():
         if comp.uid not in submit_t:
             continue
-        t_first = times[min(comp.first_token_step, len(times) - 1)]
+        t_first = times[first_at[comp.uid]]
         ttft.append(t_first - submit_t[comp.uid])
         prev = t_first
-        for es in eng.token_engine_steps(comp):
-            t_tok = times[min(es, len(times) - 1)]
-            itl.append(t_tok - prev)
-            prev = t_tok
+        for k in tok_at.get(comp.uid, []):
+            itl.append(times[k] - prev)
+            prev = times[k]
     # the structural no-head-of-line claim, at step granularity (exact on
     # any host, unlike the wall-clock percentiles which are dispatch-noise
     # bound on a CPU toy config): tokens OTHER slots emitted between a
@@ -342,8 +361,8 @@ def run_latency(cfg, params, *, requests, max_batch, capacity, buckets,
             1
             for c in eng.completions.values()
             if c.uid != lc.uid and c.uid in submit_t
-            for es in eng.token_engine_steps(c)
-            if lc.admitted_engine_step < es < lc.first_token_step)
+            for k in tok_at.get(c.uid, [])
+            if admit_at[lc.uid] <= k < first_at[lc.uid])
         during.append(n)
     pct = lambda xs, q: float(np.percentile(xs, q)) if xs else 0.0
     s = eng.stats

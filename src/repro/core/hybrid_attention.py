@@ -230,9 +230,10 @@ def chunk_prefill_attention(
     nr = spec.n_retrieval
     if perm is None:
         perm = identity_perm(spec)
-    qp = _permute_q(q, perm, g)
-    kp = _permute_kv(k_new, perm)
-    vp = _permute_kv(v_new, perm)
+    with jax.named_scope("qkv"):
+        qp = _permute_q(q, perm, g)
+        kp = _permute_kv(k_new, perm)
+        vp = _permute_kv(v_new, perm)
     b, cch = q.shape[0], q.shape[1]
     act = jnp.ones((b,), bool) if active is None else \
         jnp.asarray(active).reshape(b)
@@ -250,32 +251,41 @@ def chunk_prefill_attention(
         # position math is layout-invariant.
         k_r = kp[:, :, :nr]
         v_r = vp[:, :, :nr]
-        outs.append(kops.chunk_attention_paged(
-            qp[:, :, : nr * g], paged.k_pages, paged.v_pages,
-            paged.page_start, start, k_r, v_r, impl=spec.impl))
-        paged = cachelib.paged_cache_append_chunk(
-            paged, k_r, v_r, start, chunk_len,
-            active=act, phys_shards=phys_shards)
+        with jax.named_scope("attn.prefill"):
+            outs.append(kops.chunk_attention_paged(
+                qp[:, :, : nr * g], paged.k_pages, paged.v_pages,
+                paged.page_start, start, k_r, v_r, impl=spec.impl))
+        with jax.named_scope("attn.append"):
+            paged = cachelib.paged_cache_append_chunk(
+                paged, k_r, v_r, start, chunk_len,
+                active=act, phys_shards=phys_shards)
     if spec.n_streaming > 0:
         ns = spec.n_streaming
         k_s = kp[:, :, nr:]                                 # (B, C, Hs, D)
         v_s = vp[:, :, nr:]
-        # attend against [pre-append ring ∥ chunk keys]: ring slots can be
-        # overwritten WITHIN a chunk (positions local_cap apart share a
-        # slot), so the post-append ring would lose keys still inside an
-        # early chunk query's window
-        kr = jnp.concatenate([stream.k, k_s.transpose(0, 2, 1, 3)], axis=2)
-        vr = jnp.concatenate([stream.v, v_s.transpose(0, 2, 1, 3)], axis=2)
-        chunk_pos = jnp.broadcast_to(pos_q[:, None, :], (b, ns, cch))
-        kpos = jnp.concatenate([stream.pos, chunk_pos], axis=2)
-        valid_s = paging.chunk_stream_validity(kpos, pos_q, sink=h2.sink,
-                                               local=h2.local)
-        outs.append(kops.chunk_attention(qp[:, :, nr * g:], kr, vr, valid_s,
-                                         impl=spec.impl))
-        stream = cachelib.stream_cache_append_chunk(
-            stream, k_s, v_s, start, chunk_len, sink=h2.sink, active=act)
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
-    return _permute_q(out, _inverse_perm(perm), g), paged, stream
+        with jax.named_scope("attn.prefill"):
+            # attend against [pre-append ring ∥ chunk keys]: ring slots
+            # can be overwritten WITHIN a chunk (positions local_cap apart
+            # share a slot), so the post-append ring would lose keys
+            # still inside an early chunk query's window
+            kr = jnp.concatenate([stream.k, k_s.transpose(0, 2, 1, 3)],
+                                 axis=2)
+            vr = jnp.concatenate([stream.v, v_s.transpose(0, 2, 1, 3)],
+                                 axis=2)
+            chunk_pos = jnp.broadcast_to(pos_q[:, None, :], (b, ns, cch))
+            kpos = jnp.concatenate([stream.pos, chunk_pos], axis=2)
+            valid_s = paging.chunk_stream_validity(
+                kpos, pos_q, sink=h2.sink, local=h2.local)
+            outs.append(kops.chunk_attention(qp[:, :, nr * g:], kr, vr,
+                                             valid_s, impl=spec.impl))
+        with jax.named_scope("attn.append"):
+            stream = cachelib.stream_cache_append_chunk(
+                stream, k_s, v_s, start, chunk_len, sink=h2.sink,
+                active=act)
+    with jax.named_scope("attn.out"):
+        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
+        out = _permute_q(out, _inverse_perm(perm), g)
+    return out, paged, stream
 
 
 def _local_cap(h2: H2ealConfig) -> int:
@@ -318,9 +328,10 @@ def decode_attention(
     nr = spec.n_retrieval
     if perm is None:
         perm = identity_perm(spec)
-    qp = _permute_q(q, perm, g)
-    kp = _permute_kv(k_new, perm)
-    vp = _permute_kv(v_new, perm)
+    with jax.named_scope("qkv"):
+        qp = _permute_q(q, perm, g)
+        kp = _permute_kv(k_new, perm)
+        vp = _permute_kv(v_new, perm)
     q_r, q_s = qp[:, : nr * g], qp[:, nr * g:]
     k_r, k_s = kp[:, :nr], kp[:, nr:]
     v_r, v_s = vp[:, :nr], vp[:, nr:]
@@ -328,40 +339,49 @@ def decode_attention(
 
     outs = []
     if nr > 0:
-        paged = cachelib.paged_cache_append(paged, k_r, v_r, length,
-                                            active=active)
+        with jax.named_scope("attn.append"):
+            paged = cachelib.paged_cache_append(paged, k_r, v_r, length,
+                                                active=active)
         if do_select:
-            scores = paging.score_pages(
-                q_r, paged.tau_min, paged.tau_max, paged.page_start, ctx,
-                sink=h2.sink, local=h2.local, page=h2.page_size,
-                impl=spec.impl)
-            sel = paging.select_pages(scores, h2.top_k_pages)
-            imp = paging.accumulate_importance(paged.importance, scores)
-            if need_select is not None:
-                ns = need_select[:, None, None]
-                sel = jnp.where(ns, sel, paged.sel_idx)
-                imp = jnp.where(ns, imp, paged.importance)
+            with jax.named_scope("attn.select"):
+                scores = paging.score_pages(
+                    q_r, paged.tau_min, paged.tau_max, paged.page_start,
+                    ctx, sink=h2.sink, local=h2.local, page=h2.page_size,
+                    impl=spec.impl)
+                sel = paging.select_pages(scores, h2.top_k_pages)
+                imp = paging.accumulate_importance(paged.importance, scores)
+                if need_select is not None:
+                    ns = need_select[:, None, None]
+                    sel = jnp.where(ns, sel, paged.sel_idx)
+                    imp = jnp.where(ns, imp, paged.importance)
             paged = dataclasses.replace(paged, sel_idx=sel, importance=imp)
-        slots = paging.attended_page_slots(
-            paged.sel_idx, ctx, sink=h2.sink, local=h2.local,
-            page=h2.page_size)
-        gk, gv = paging.gather_pages(paged.k_pages, paged.v_pages, slots)
-        valid = paging.token_validity(
-            slots, paged.page_start, ctx, sink=h2.sink, local=h2.local,
-            page=h2.page_size, top_k=h2.top_k_pages)
-        outs.append(kops.paged_attention(q_r, gk, gv, valid, impl=spec.impl))
+        with jax.named_scope("attn.gather"):
+            slots = paging.attended_page_slots(
+                paged.sel_idx, ctx, sink=h2.sink, local=h2.local,
+                page=h2.page_size)
+            gk, gv = paging.gather_pages(paged.k_pages, paged.v_pages,
+                                         slots)
+        with jax.named_scope("attn.retrieval"):
+            valid = paging.token_validity(
+                slots, paged.page_start, ctx, sink=h2.sink, local=h2.local,
+                page=h2.page_size, top_k=h2.top_k_pages)
+            outs.append(kops.paged_attention(q_r, gk, gv, valid,
+                                             impl=spec.impl))
     if spec.n_streaming > 0:
-        stream = cachelib.stream_cache_append(
-            stream, k_s, v_s, length, sink=h2.sink, active=active)
-        # exact sink+local mask (ring carries one page of slack)
-        ctx_b = jnp.broadcast_to(jnp.asarray(ctx, jnp.int32),
-                                 (q.shape[0],))[:, None, None]
-        valid_s = (stream.pos >= 0) & (
-            (stream.pos < h2.sink) | (stream.pos >= ctx_b - h2.local))
-        outs.append(kops.paged_attention(
-            q_s, stream.k, stream.v, valid_s, impl=spec.impl))
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-    out = _permute_q(out, _inverse_perm(perm), g)
+        with jax.named_scope("attn.append"):
+            stream = cachelib.stream_cache_append(
+                stream, k_s, v_s, length, sink=h2.sink, active=active)
+        with jax.named_scope("attn.streaming"):
+            # exact sink+local mask (ring carries one page of slack)
+            ctx_b = jnp.broadcast_to(jnp.asarray(ctx, jnp.int32),
+                                     (q.shape[0],))[:, None, None]
+            valid_s = (stream.pos >= 0) & (
+                (stream.pos < h2.sink) | (stream.pos >= ctx_b - h2.local))
+            outs.append(kops.paged_attention(
+                q_s, stream.k, stream.v, valid_s, impl=spec.impl))
+    with jax.named_scope("attn.out"):
+        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+        out = _permute_q(out, _inverse_perm(perm), g)
     return out, paged, stream
 
 
@@ -412,9 +432,10 @@ def chunk_verify_attention(
     nr = spec.n_retrieval
     if perm is None:
         perm = identity_perm(spec)
-    qp = _permute_q(q, perm, g)
-    kp = _permute_kv(k_new, perm)
-    vp = _permute_kv(v_new, perm)
+    with jax.named_scope("qkv"):
+        qp = _permute_q(q, perm, g)
+        kp = _permute_kv(k_new, perm)
+        vp = _permute_kv(v_new, perm)
     b, kch = q.shape[0], q.shape[1]
     act = jnp.ones((b,), bool) if active is None else \
         jnp.asarray(active).reshape(b)
@@ -429,48 +450,58 @@ def chunk_verify_attention(
         q_r = qp[:, :, : nr * g]                            # (B, k, HqR, D)
         k_r = kp[:, :, :nr]
         v_r = vp[:, :, :nr]
-        scores = paging.score_pages(
-            q_r[:, 0], paged.tau_min, paged.tau_max, paged.page_start,
-            ctx1, sink=h2.sink, local=h2.local, page=h2.page_size,
-            impl=spec.impl)
-        sel = paging.select_pages(scores, h2.top_k_pages)
-        imp = paging.accumulate_importance(paged.importance, scores)
-        ns = (need & act)[:, None, None]
-        sel = jnp.where(ns, sel, paged.sel_idx)
-        imp = jnp.where(ns, imp, paged.importance)
+        with jax.named_scope("attn.select"):
+            scores = paging.score_pages(
+                q_r[:, 0], paged.tau_min, paged.tau_max, paged.page_start,
+                ctx1, sink=h2.sink, local=h2.local, page=h2.page_size,
+                impl=spec.impl)
+            sel = paging.select_pages(scores, h2.top_k_pages)
+            imp = paging.accumulate_importance(paged.importance, scores)
+            ns = (need & act)[:, None, None]
+            sel = jnp.where(ns, sel, paged.sel_idx)
+            imp = jnp.where(ns, imp, paged.importance)
         paged = dataclasses.replace(paged, sel_idx=sel, importance=imp)
-        slots = paging.verify_attended_slots(
-            paged.sel_idx, ctx1, sink=h2.sink, local=h2.local,
-            page=h2.page_size, capacity=paged.k_pages.shape[2],
-            n_shards=phys_shards)
-        gk, gv = paging.gather_pages(paged.k_pages, paged.v_pages, slots)
-        valid_p = paging.verify_token_validity(
-            slots, paged.page_start, start, pos_q, sink=h2.sink,
-            local=h2.local, page=h2.page_size, top_k=h2.top_k_pages)
-        kr = jnp.concatenate(
-            [gk, k_r.transpose(0, 2, 1, 3).astype(gk.dtype)], axis=2)
-        vr = jnp.concatenate(
-            [gv, v_r.transpose(0, 2, 1, 3).astype(gv.dtype)], axis=2)
-        tail = jnp.tril(jnp.ones((kch, kch), bool))         # key i <= query j
-        valid = jnp.concatenate([
-            valid_p,
-            jnp.broadcast_to(tail[None, None], (b, nr, kch, kch)),
-        ], axis=3)
-        outs.append(kops.chunk_attention(q_r, kr, vr, valid, impl=spec.impl))
+        with jax.named_scope("attn.gather"):
+            slots = paging.verify_attended_slots(
+                paged.sel_idx, ctx1, sink=h2.sink, local=h2.local,
+                page=h2.page_size, capacity=paged.k_pages.shape[2],
+                n_shards=phys_shards)
+            gk, gv = paging.gather_pages(paged.k_pages, paged.v_pages,
+                                         slots)
+        with jax.named_scope("attn.retrieval"):
+            valid_p = paging.verify_token_validity(
+                slots, paged.page_start, start, pos_q, sink=h2.sink,
+                local=h2.local, page=h2.page_size, top_k=h2.top_k_pages)
+            kr = jnp.concatenate(
+                [gk, k_r.transpose(0, 2, 1, 3).astype(gk.dtype)], axis=2)
+            vr = jnp.concatenate(
+                [gv, v_r.transpose(0, 2, 1, 3).astype(gv.dtype)], axis=2)
+            tail = jnp.tril(jnp.ones((kch, kch), bool))     # key i <= query j
+            valid = jnp.concatenate([
+                valid_p,
+                jnp.broadcast_to(tail[None, None], (b, nr, kch, kch)),
+            ], axis=3)
+            outs.append(kops.chunk_attention(q_r, kr, vr, valid,
+                                             impl=spec.impl))
     if spec.n_streaming > 0:
         n_s = spec.n_streaming
         k_s = kp[:, :, nr:]
         v_s = vp[:, :, nr:]
-        kr = jnp.concatenate([stream.k, k_s.transpose(0, 2, 1, 3)], axis=2)
-        vr = jnp.concatenate([stream.v, v_s.transpose(0, 2, 1, 3)], axis=2)
-        chunk_pos = jnp.broadcast_to(pos_q[:, None, :], (b, n_s, kch))
-        kpos = jnp.concatenate([stream.pos, chunk_pos], axis=2)
-        valid_s = paging.chunk_stream_validity(kpos, pos_q, sink=h2.sink,
-                                               local=h2.local)
-        outs.append(kops.chunk_attention(qp[:, :, nr * g:], kr, vr, valid_s,
-                                         impl=spec.impl))
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
-    return _permute_q(out, _inverse_perm(perm), g), paged, stream
+        with jax.named_scope("attn.streaming"):
+            kr = jnp.concatenate([stream.k, k_s.transpose(0, 2, 1, 3)],
+                                 axis=2)
+            vr = jnp.concatenate([stream.v, v_s.transpose(0, 2, 1, 3)],
+                                 axis=2)
+            chunk_pos = jnp.broadcast_to(pos_q[:, None, :], (b, n_s, kch))
+            kpos = jnp.concatenate([stream.pos, chunk_pos], axis=2)
+            valid_s = paging.chunk_stream_validity(
+                kpos, pos_q, sink=h2.sink, local=h2.local)
+            outs.append(kops.chunk_attention(qp[:, :, nr * g:], kr, vr,
+                                             valid_s, impl=spec.impl))
+    with jax.named_scope("attn.out"):
+        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
+        out = _permute_q(out, _inverse_perm(perm), g)
+    return out, paged, stream
 
 
 def chunk_verify_append(
@@ -495,19 +526,20 @@ def chunk_verify_append(
     nr = spec.n_retrieval
     if perm is None:
         perm = identity_perm(spec)
-    kp = _permute_kv(k_new, perm)
-    vp = _permute_kv(v_new, perm)
     b = k_new.shape[0]
     act = jnp.ones((b,), bool) if active is None else \
         jnp.asarray(active).reshape(b)
-    if nr > 0:
-        paged = cachelib.paged_cache_append_chunk(
-            paged, kp[:, :, :nr], vp[:, :, :nr], start, accepted,
-            active=act, phys_shards=phys_shards)
-    if spec.n_streaming > 0:
-        stream = cachelib.stream_cache_append_chunk(
-            stream, kp[:, :, nr:], vp[:, :, nr:], start, accepted,
-            sink=h2.sink, active=act)
+    with jax.named_scope("attn.append"):
+        kp = _permute_kv(k_new, perm)
+        vp = _permute_kv(v_new, perm)
+        if nr > 0:
+            paged = cachelib.paged_cache_append_chunk(
+                paged, kp[:, :, :nr], vp[:, :, :nr], start, accepted,
+                active=act, phys_shards=phys_shards)
+        if spec.n_streaming > 0:
+            stream = cachelib.stream_cache_append_chunk(
+                stream, kp[:, :, nr:], vp[:, :, nr:], start, accepted,
+                sink=h2.sink, active=act)
     return paged, stream
 
 
@@ -536,51 +568,64 @@ def decode_attention_pool(
     nr = spec.n_retrieval
     if perm is None:
         perm = identity_perm(spec)
-    qp = _permute_q(q, perm, g)
-    kp = _permute_kv(k_new, perm)
-    vp = _permute_kv(v_new, perm)
+    with jax.named_scope("qkv"):
+        qp = _permute_q(q, perm, g)
+        kp = _permute_kv(k_new, perm)
+        vp = _permute_kv(v_new, perm)
     q_r, q_s = qp[:, : nr * g], qp[:, nr * g:]
     ctx = length + 1
     p_sz = h2.page_size
 
     outs = []
     if nr > 0:
-        paged = cachelib.pool_append(
-            paged, kp[:, :nr], vp[:, :nr], length,
-            page=p_sz, sink=h2.sink, local=h2.local)
+        with jax.named_scope("attn.append"):
+            paged = cachelib.pool_append(
+                paged, kp[:, :nr], vp[:, :nr], length,
+                page=p_sz, sink=h2.sink, local=h2.local)
         if do_select:
-            scores = paging.score_pages(
-                q_r, paged.tau_min, paged.tau_max, paged.page_start, ctx,
-                sink=h2.sink, local=h2.local, page=p_sz, impl=spec.impl)
-            sel = paging.select_pages(scores, h2.top_k_pages)
-            paged = dataclasses.replace(
-                paged, sel_idx=sel,
-                importance=paging.accumulate_importance(
-                    paged.importance, scores))
-        # sink/local slots by position lookup (pool slots are arbitrary)
-        n_sink, n_local = paging.page_counts(sink=h2.sink, local=h2.local,
-                                             page=p_sz)
-        first_local = jnp.maximum(ctx - h2.local, 0) // p_sz
-        sink_pos = jnp.arange(n_sink, dtype=jnp.int32) * p_sz
-        local_pos = (first_local + jnp.arange(n_local, dtype=jnp.int32)) * p_sz
-        sink_slots = paging.slots_of_positions(paged.page_start, sink_pos)
-        local_slots = paging.slots_of_positions(paged.page_start, local_pos)
-        slots = jnp.concatenate([sink_slots, paged.sel_idx, local_slots],
-                                axis=2)
-        gk, gv = paging.gather_pages(paged.k_pages, paged.v_pages, slots)
-        valid = paging.token_validity(
-            slots, paged.page_start, ctx, sink=h2.sink, local=h2.local,
-            page=p_sz, top_k=h2.top_k_pages)
-        outs.append(kops.paged_attention(q_r, gk, gv, valid, impl=spec.impl))
+            with jax.named_scope("attn.select"):
+                scores = paging.score_pages(
+                    q_r, paged.tau_min, paged.tau_max, paged.page_start,
+                    ctx, sink=h2.sink, local=h2.local, page=p_sz,
+                    impl=spec.impl)
+                sel = paging.select_pages(scores, h2.top_k_pages)
+                imp = paging.accumulate_importance(paged.importance, scores)
+            paged = dataclasses.replace(paged, sel_idx=sel, importance=imp)
+        with jax.named_scope("attn.gather"):
+            # sink/local slots by position lookup (pool slots are
+            # arbitrary)
+            n_sink, n_local = paging.page_counts(
+                sink=h2.sink, local=h2.local, page=p_sz)
+            first_local = jnp.maximum(ctx - h2.local, 0) // p_sz
+            sink_pos = jnp.arange(n_sink, dtype=jnp.int32) * p_sz
+            local_pos = (first_local
+                         + jnp.arange(n_local, dtype=jnp.int32)) * p_sz
+            sink_slots = paging.slots_of_positions(paged.page_start,
+                                                   sink_pos)
+            local_slots = paging.slots_of_positions(paged.page_start,
+                                                    local_pos)
+            slots = jnp.concatenate([sink_slots, paged.sel_idx,
+                                     local_slots], axis=2)
+            gk, gv = paging.gather_pages(paged.k_pages, paged.v_pages,
+                                         slots)
+        with jax.named_scope("attn.retrieval"):
+            valid = paging.token_validity(
+                slots, paged.page_start, ctx, sink=h2.sink, local=h2.local,
+                page=p_sz, top_k=h2.top_k_pages)
+            outs.append(kops.paged_attention(q_r, gk, gv, valid,
+                                             impl=spec.impl))
     if spec.n_streaming > 0:
-        stream = cachelib.stream_cache_append(
-            stream, kp[:, nr:], vp[:, nr:], length, sink=h2.sink)
-        valid_s = (stream.pos >= 0) & (
-            (stream.pos < h2.sink) | (stream.pos >= ctx - h2.local))
-        outs.append(kops.paged_attention(
-            q_s, stream.k, stream.v, valid_s, impl=spec.impl))
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-    out = _permute_q(out, _inverse_perm(perm), g)
+        with jax.named_scope("attn.append"):
+            stream = cachelib.stream_cache_append(
+                stream, kp[:, nr:], vp[:, nr:], length, sink=h2.sink)
+        with jax.named_scope("attn.streaming"):
+            valid_s = (stream.pos >= 0) & (
+                (stream.pos < h2.sink) | (stream.pos >= ctx - h2.local))
+            outs.append(kops.paged_attention(
+                q_s, stream.k, stream.v, valid_s, impl=spec.impl))
+    with jax.named_scope("attn.out"):
+        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+        out = _permute_q(out, _inverse_perm(perm), g)
     return out, paged, stream
 
 
@@ -671,76 +716,88 @@ def _paged_decode_coplace(spec: AttnSpec, q_r, k_r, v_r,
         act = extra[0] if ragged else None
         need = extra[1] if (ragged and do_select) else None
         # ---- append (only the owner shard writes; retired slots masked) --
-        kp, vp, tmin, tmax, pstart = cachelib.sharded_paged_append(
-            kp, vp, tmin, tmax, pstart, kn, vn, length, page=p_sz,
-            shard_idx=i, n_shards=nsh, active=act)
+        with jax.named_scope("attn.append"):
+            kp, vp, tmin, tmax, pstart = cachelib.sharded_paged_append(
+                kp, vp, tmin, tmax, pstart, kn, vn, length, page=p_sz,
+                shard_idx=i, n_shards=nsh, active=act)
 
         # ---- selection (local score + distributed top-k) ----
         if do_select:
-            scores_loc = paging.score_pages(
-                q, tmin, tmax, pstart, ctx, sink=h2.sink, local=h2.local,
-                page=p_sz, impl=spec.impl)          # (B, Hr, C_loc)
-            imp_new = paging.accumulate_importance(imp, scores_loc)
-            k_eff = min(h2.top_k_pages, c_loc)
-            v_loc, i_loc = jax.lax.top_k(scores_loc, k_eff)
-            phys_loc = i_loc + i * c_loc
-            v_all = jax.lax.all_gather(v_loc, axis)   # (nsh, B, Hr, k)
-            i_all = jax.lax.all_gather(phys_loc, axis)
-            bsz, hr = v_loc.shape[0], v_loc.shape[1]
-            v_cat = v_all.transpose(1, 2, 0, 3).reshape(bsz, hr, nsh * k_eff)
-            i_cat = i_all.transpose(1, 2, 0, 3).reshape(bsz, hr, nsh * k_eff)
-            sel_v, sel_pos = jax.lax.top_k(v_cat, min(h2.top_k_pages,
-                                                      nsh * k_eff))
-            sel = jnp.take_along_axis(i_cat, sel_pos, axis=2)
-            sel = jnp.where(sel_v > paging.SELECTABLE_MIN, sel, -1)
-            if sel.shape[2] < h2.top_k_pages:
-                pad = jnp.full(sel.shape[:2] + (h2.top_k_pages
-                                                - sel.shape[2],), -1,
-                               jnp.int32)
-                sel = jnp.concatenate([sel.astype(jnp.int32), pad], axis=2)
-            sel = sel.astype(jnp.int32)
-            if need is not None:
-                # per-slot share window: slots whose window has not expired
-                # keep their cached selection / importance bit-unchanged
-                ns = need[:, None, None]
-                sel = jnp.where(ns, sel, sel_prev)
-                imp = jnp.where(ns, imp_new, imp)
-            else:
-                imp = imp_new
+            with jax.named_scope("attn.select"):
+                scores_loc = paging.score_pages(
+                    q, tmin, tmax, pstart, ctx, sink=h2.sink,
+                    local=h2.local, page=p_sz,
+                    impl=spec.impl)                 # (B, Hr, C_loc)
+                imp_new = paging.accumulate_importance(imp, scores_loc)
+                k_eff = min(h2.top_k_pages, c_loc)
+                v_loc, i_loc = jax.lax.top_k(scores_loc, k_eff)
+                phys_loc = i_loc + i * c_loc
+                v_all = jax.lax.all_gather(v_loc, axis)  # (nsh, B, Hr, k)
+                i_all = jax.lax.all_gather(phys_loc, axis)
+                bsz, hr = v_loc.shape[0], v_loc.shape[1]
+                v_cat = v_all.transpose(1, 2, 0, 3).reshape(
+                    bsz, hr, nsh * k_eff)
+                i_cat = i_all.transpose(1, 2, 0, 3).reshape(
+                    bsz, hr, nsh * k_eff)
+                sel_v, sel_pos = jax.lax.top_k(v_cat, min(h2.top_k_pages,
+                                                          nsh * k_eff))
+                sel = jnp.take_along_axis(i_cat, sel_pos, axis=2)
+                sel = jnp.where(sel_v > paging.SELECTABLE_MIN, sel, -1)
+                if sel.shape[2] < h2.top_k_pages:
+                    pad = jnp.full(sel.shape[:2] + (h2.top_k_pages
+                                                    - sel.shape[2],), -1,
+                                   jnp.int32)
+                    sel = jnp.concatenate([sel.astype(jnp.int32), pad],
+                                          axis=2)
+                sel = sel.astype(jnp.int32)
+                if need is not None:
+                    # per-slot share window: slots whose window has not
+                    # expired keep their cached selection / importance
+                    # bit-unchanged
+                    ns = need[:, None, None]
+                    sel = jnp.where(ns, sel, sel_prev)
+                    imp = jnp.where(ns, imp_new, imp)
+                else:
+                    imp = imp_new
         else:
             sel = sel_prev
 
         # ---- attended slots (physical) + local partial attention ----
-        slots_phys = paging.coplace_attended_slots(
-            sel, ctx, sink=h2.sink, local=h2.local, page=p_sz,
-            capacity=cap_pages, n_shards=nsh)
-        loc = slots_phys - i * c_loc
-        mine_s = (slots_phys >= 0) & (loc >= 0) & (loc < c_loc)
-        loc_masked = jnp.where(mine_s, loc, -1)
-        gk, gv = paging.gather_pages(kp, vp, loc_masked)
-        valid = paging.token_validity(
-            loc_masked, pstart, ctx, sink=h2.sink, local=h2.local,
-            page=p_sz, top_k=h2.top_k_pages)
-        m, l, o = kops.paged_attention_partial(q, gk, gv, valid,
-                                               impl=spec.impl)
+        with jax.named_scope("attn.gather"):
+            slots_phys = paging.coplace_attended_slots(
+                sel, ctx, sink=h2.sink, local=h2.local, page=p_sz,
+                capacity=cap_pages, n_shards=nsh)
+            loc = slots_phys - i * c_loc
+            mine_s = (slots_phys >= 0) & (loc >= 0) & (loc < c_loc)
+            loc_masked = jnp.where(mine_s, loc, -1)
+            gk, gv = paging.gather_pages(kp, vp, loc_masked)
+        with jax.named_scope("attn.retrieval"):
+            valid = paging.token_validity(
+                loc_masked, pstart, ctx, sink=h2.sink, local=h2.local,
+                page=p_sz, top_k=h2.top_k_pages)
+            m, l, o = kops.paged_attention_partial(q, gk, gv, valid,
+                                                   impl=spec.impl)
 
-        # ---- cross-shard flash combine (the paper's cross-bank softmax) --
-        if use_pallas:
-            # fused epilogue: ship each shard's (2+D) floats per head and
-            # run the max/rescale/sum/divide merge as one kernel
-            m_all = jax.lax.all_gather(m, axis)      # (nsh, B, HqR)
-            l_all = jax.lax.all_gather(l, axis)
-            o_all = jax.lax.all_gather(o, axis)      # (nsh, B, HqR, D)
-            out = kops.combine_partials(m_all, l_all, o_all,
-                                        impl=spec.impl).astype(q.dtype)
-        else:
-            m_max = jax.lax.pmax(m, axis)
-            corr = jnp.where(jnp.isfinite(m),
-                             jnp.exp(m - jnp.where(jnp.isfinite(m_max),
-                                                   m_max, 0.0)), 0.0)
-            l_g = jax.lax.psum(l * corr, axis)
-            o_g = jax.lax.psum(o * corr[..., None].astype(o.dtype), axis)
-            out = (o_g / jnp.maximum(l_g, 1e-30)[..., None]).astype(q.dtype)
+            # ---- cross-shard flash combine (the paper's cross-bank
+            # softmax) ----
+            if use_pallas:
+                # fused epilogue: ship each shard's (2+D) floats per head
+                # and run the max/rescale/sum/divide merge as one kernel
+                m_all = jax.lax.all_gather(m, axis)      # (nsh, B, HqR)
+                l_all = jax.lax.all_gather(l, axis)
+                o_all = jax.lax.all_gather(o, axis)      # (nsh, B, HqR, D)
+                out = kops.combine_partials(m_all, l_all, o_all,
+                                            impl=spec.impl).astype(q.dtype)
+            else:
+                m_max = jax.lax.pmax(m, axis)
+                corr = jnp.where(jnp.isfinite(m),
+                                 jnp.exp(m - jnp.where(jnp.isfinite(m_max),
+                                                       m_max, 0.0)), 0.0)
+                l_g = jax.lax.psum(l * corr, axis)
+                o_g = jax.lax.psum(o * corr[..., None].astype(o.dtype),
+                                   axis)
+                out = (o_g / jnp.maximum(l_g, 1e-30)[..., None]).astype(
+                    q.dtype)
         return out, kp, vp, tmin, tmax, imp, pstart, sel
 
     len_spec = vec if ragged else P()
@@ -786,9 +843,10 @@ def decode_attention_coplace(spec: AttnSpec, q, k_new, v_new, paged, stream,
     nr = spec.n_retrieval
     if perm is None:
         perm = identity_perm(spec)
-    qp = _permute_q(q, perm, g)
-    kp = _permute_kv(k_new, perm)
-    vp = _permute_kv(v_new, perm)
+    with jax.named_scope("qkv"):
+        qp = _permute_q(q, perm, g)
+        kp = _permute_kv(k_new, perm)
+        vp = _permute_kv(v_new, perm)
     ctx = length + 1
     outs = []
     if nr > 0:
@@ -798,17 +856,21 @@ def decode_attention_coplace(spec: AttnSpec, q, k_new, v_new, paged, stream,
             need_select=need_select)
         outs.append(out_r)
     if spec.n_streaming > 0:
-        stream = cachelib.stream_cache_append(
-            stream, kp[:, nr:], vp[:, nr:], length, sink=h2.sink,
-            active=active)
-        ctx_b = jnp.broadcast_to(jnp.asarray(ctx, jnp.int32),
-                                 (q.shape[0],))[:, None, None]
-        valid_s = (stream.pos >= 0) & (
-            (stream.pos < h2.sink) | (stream.pos >= ctx_b - h2.local))
-        outs.append(kops.paged_attention(
-            qp[:, nr * g:], stream.k, stream.v, valid_s, impl=spec.impl))
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-    out = _permute_q(out, _inverse_perm(perm), g)
+        with jax.named_scope("attn.append"):
+            stream = cachelib.stream_cache_append(
+                stream, kp[:, nr:], vp[:, nr:], length, sink=h2.sink,
+                active=active)
+        with jax.named_scope("attn.streaming"):
+            ctx_b = jnp.broadcast_to(jnp.asarray(ctx, jnp.int32),
+                                     (q.shape[0],))[:, None, None]
+            valid_s = (stream.pos >= 0) & (
+                (stream.pos < h2.sink) | (stream.pos >= ctx_b - h2.local))
+            outs.append(kops.paged_attention(
+                qp[:, nr * g:], stream.k, stream.v, valid_s,
+                impl=spec.impl))
+    with jax.named_scope("attn.out"):
+        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+        out = _permute_q(out, _inverse_perm(perm), g)
     return out, paged, stream
 
 

@@ -122,6 +122,7 @@ def chunk_attention(q, k, v, valid, *, bt=512, interpret=False):
     nt = pl.cdiv(t, bt_)
     out = pl.pallas_call(
         functools.partial(_chunk_kernel, bt=bt_, seq_t=t, group=g),
+        name="chunk_attention",
         grid=(b * h_kv, nt),
         in_specs=[
             pl.BlockSpec((1, g, cq, d), lambda bh, ti: (bh, 0, 0, 0)),
@@ -229,6 +230,7 @@ def chunk_attention_paged(q, k_pages, v_pages, page_start, start, k_new,
     out = pl.pallas_call(
         functools.partial(_paged_kernel, bt=bt_, seq_t=t, npt=npt, cq=cq,
                           group=g),
+        name="chunk_attention_paged",
         grid=(bh, npt + 1),
         in_specs=[
             pl.BlockSpec((1, cq * g, d), lambda bh_, ti: (bh_, 0, 0)),

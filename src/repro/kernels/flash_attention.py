@@ -98,6 +98,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, sink=0, q_offset=0,
         functools.partial(_kernel, causal=causal, window=window, sink=sink,
                           q_offset=q_offset, bq=bq_, bk=bk_, seq_q=sq,
                           seq_k=sk_len),
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq_, d), lambda bh, qi, ki: (bh, qi, 0)),

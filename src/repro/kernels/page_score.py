@@ -45,6 +45,7 @@ def page_score(q, tau_min, tau_max, *, bc=512, interpret=False):
     nc = pl.cdiv(c, bc_)
     out = pl.pallas_call(
         _kernel,
+        name="page_score",
         grid=(b * h_kv, nc),
         in_specs=[
             pl.BlockSpec((1, g, d), lambda bh, ci: (bh, 0, 0)),

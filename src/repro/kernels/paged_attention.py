@@ -75,7 +75,7 @@ def _stream_tile(q_ref, k_ref, v_ref, valid_ref, m_ref, l_ref, acc_ref, *,
     m_ref[...] = m_new
 
 
-def _stream_call(kernel, q, k, v, valid, *, bt, interpret, out_specs,
+def _stream_call(kernel, q, k, v, valid, *, name, bt, interpret, out_specs,
                  out_shape):
     """Shared pallas_call setup for the KV-streaming decode kernels:
     fold (B, Hkv) into the BH grid axis, tile T by ``bt``, and allocate
@@ -94,6 +94,7 @@ def _stream_call(kernel, q, k, v, valid, *, bt, interpret, out_specs,
     nt = pl.cdiv(t, bt_)
     return pl.pallas_call(
         functools.partial(kernel, bt=bt_, seq_t=t),
+        name=name,
         grid=(b * h_kv, nt),
         in_specs=[
             pl.BlockSpec((1, g, d), lambda bh, ti: (bh, 0, 0)),
@@ -133,7 +134,8 @@ def paged_attention(q, k, v, valid, *, bt=512, interpret=False):
     h_kv = k.shape[1]
     g = hq // h_kv
     out = _stream_call(
-        _kernel, q, k, v, valid, bt=bt, interpret=interpret,
+        _kernel, q, k, v, valid, name="paged_attention", bt=bt,
+        interpret=interpret,
         out_specs=pl.BlockSpec((1, g, d), lambda bh, ti: (bh, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h_kv, g, d), q.dtype))
     return out.reshape(b, hq, d)
@@ -168,7 +170,8 @@ def paged_attention_partial(q, k, v, valid, *, bt=512, interpret=False):
     h_kv = k.shape[1]
     g = hq // h_kv
     m, l, o = _stream_call(
-        _partial_kernel, q, k, v, valid, bt=bt, interpret=interpret,
+        _partial_kernel, q, k, v, valid, name="paged_attention_partial",
+        bt=bt, interpret=interpret,
         # m/l carry a trailing unit dim: (BH, G, 1) blocks of (1, G, 1)
         # store the (G, 1) scratch as is, with no rank-changing cast
         out_specs=[
@@ -221,6 +224,7 @@ def combine_partials(m, l, o, *, br=128, interpret=False):
     nr = pl.cdiv(r, br_)
     out = pl.pallas_call(
         functools.partial(_combine_kernel, br=br_, n_rows=r),
+        name="paged_attention_combine",
         grid=(nr,),
         in_specs=[
             pl.BlockSpec((n, br_, 1), lambda ri: (0, ri, 0)),

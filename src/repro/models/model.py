@@ -31,14 +31,16 @@ def embed_input(cfg: ArchConfig, params, batch) -> Array:
     frontend-stub archs (vlm/audio)."""
     if cfg.embed_frontend_stub:
         return batch  # precomputed frame/patch embeddings
-    return jnp.take(params["embed"], batch, axis=0)
+    with jax.named_scope("embed"):
+        return jnp.take(params["embed"], batch, axis=0)
 
 
 def unembed(cfg: ArchConfig, params, x: Array) -> Array:
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        return jnp.einsum("...d,vd->...v", x, params["embed"])
-    return jnp.einsum("...d,dv->...v", x, params["lm_head"])
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if cfg.tie_embeddings:
+            return jnp.einsum("...d,vd->...v", x, params["embed"])
+        return jnp.einsum("...d,dv->...v", x, params["lm_head"])
 
 
 def _rope(cfg: ArchConfig, positions: Array):
@@ -163,10 +165,11 @@ def prefill_chunk(cfg: ArchConfig, params, state, tokens, *, chunk_len,
         "frontend-stub archs use prefill-then-pack admission")
     plan = plan if plan is not None else T.default_plan(cfg)
     start = jnp.asarray(state["length"], jnp.int32).reshape(-1)   # (B,)
-    x = jnp.take(params["embed"], tokens, axis=0)                 # (B,C,d)
+    x = embed_input(cfg, params, tokens)                          # (B,C,d)
     cch = tokens.shape[1]
-    pos_q = start[:, None] + jnp.arange(cch, dtype=jnp.int32)
-    rope = _rope(cfg, pos_q)                                      # (B,C,half)
+    with jax.named_scope("qkv"):
+        pos_q = start[:, None] + jnp.arange(cch, dtype=jnp.int32)
+        rope = _rope(cfg, pos_q)                                  # (B,C,half)
     chunk_len = jnp.asarray(chunk_len, jnp.int32).reshape(-1)
     active = jnp.asarray(active).reshape(-1)
     n_per, n_rem = T.layer_layout(cfg)
@@ -201,8 +204,9 @@ def prefill_chunk(cfg: ArchConfig, params, state, tokens, *, chunk_len,
         new_state["rem"][f"rem{r}"] = c
     # logits at each slot's LAST valid chunk position (first-token
     # emission for slots whose prompt completed this step)
-    idx = jnp.clip(chunk_len - 1, 0, cch - 1)
-    x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
+    with jax.named_scope("head"):
+        idx = jnp.clip(chunk_len - 1, 0, cch - 1)
+        x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
     return unembed(cfg, params, x_last), new_state
 
 
@@ -223,10 +227,12 @@ def verify_forward(cfg: ArchConfig, params, state, tokens, *, active,
     """
     plan = plan if plan is not None else T.default_plan(cfg)
     start = jnp.asarray(state["length"], jnp.int32).reshape(-1)   # (B,)
-    x = jnp.take(params["embed"], tokens, axis=0)                 # (B,k,d)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)             # (B,k,d)
     kch = tokens.shape[1]
-    pos_q = start[:, None] + jnp.arange(kch, dtype=jnp.int32)
-    rope = _rope(cfg, pos_q)                                      # (B,k,half)
+    with jax.named_scope("qkv"):
+        pos_q = start[:, None] + jnp.arange(kch, dtype=jnp.int32)
+        rope = _rope(cfg, pos_q)                                  # (B,k,half)
     active = jnp.asarray(active).reshape(-1)
     need_select = jnp.asarray(need_select).reshape(-1)
     n_per, n_rem = T.layer_layout(cfg)
@@ -321,13 +327,12 @@ def decode_step(cfg: ArchConfig, params, state, token, *, plan=None,
     """
     plan = plan if plan is not None else T.default_plan(cfg)
     length = state["length"]
-    if cfg.embed_frontend_stub:
-        x = token
-    else:
-        x = jnp.take(params["embed"], token, axis=0)
-    # rope at each slot's own position: (1, half) lockstep / (B, half) ragged
-    rope1 = _rope(cfg, jnp.reshape(length, (-1,)))
-    rope1 = (rope1[0][:, None], rope1[1][:, None])  # (·, 1, half) broadcast
+    x = embed_input(cfg, params, token)
+    with jax.named_scope("qkv"):
+        # rope at each slot's own position: (1, half) lockstep / (B, half)
+        # ragged
+        rope1 = _rope(cfg, jnp.reshape(length, (-1,)))
+        rope1 = (rope1[0][:, None], rope1[1][:, None])  # (·, 1, half)
     n_per, n_rem = T.layer_layout(cfg)
     p_len = T.period_len(cfg)
 

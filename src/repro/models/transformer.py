@@ -199,11 +199,12 @@ def default_plan(cfg: ArchConfig):
 
 
 def _ffn_apply(cfg: ArchConfig, p, x):
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if "moe" in p:
-        return x + moelib.moe_ffn(cfg, p["moe"], h)
-    f = p["ffn"]
-    return x + swiglu(h, f["w_gate"], f["w_up"], f["w_down"])
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, p["ln2"], cfg.norm_eps)
+        if "moe" in p:
+            return x + moelib.moe_ffn(cfg, p["moe"], h)
+        f = p["ffn"]
+        return x + swiglu(h, f["w_gate"], f["w_up"], f["w_down"])
 
 
 def _qkv(cfg: ArchConfig, p, h):
@@ -332,10 +333,11 @@ def block_prefill_chunk(cfg: ArchConfig, pos: int, p, plan, x, rope, cache,
     b, cch = x.shape[0], x.shape[1]
     if mixer == MIXER_ATTENTION:
         spec = attn_spec(cfg, pos, impl)
-        q, k, v = _qkv(cfg, p, h)
-        cos, sin = rope
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        with jax.named_scope("qkv"):
+            q, k, v = _qkv(cfg, p, h)
+            cos, sin = rope
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
         if spec.h2.enabled and spec.window == 0:
             inputs = layoutlib.PrefillInputs(
                 q=q, k_new=k, v_new=v, start=start, chunk_len=chunk_len,
@@ -344,21 +346,24 @@ def block_prefill_chunk(cfg: ArchConfig, pos: int, p, plan, x, rope, cache,
                 layout, spec, cache, inputs, perm=plan["perm"])
         else:  # full-attention baseline / plain window layer
             from repro.core import paging
-            full = cachelib.full_cache_append_chunk(
-                cache["full"], k, v, start, chunk_len, active=active)
-            pos_q = paging.chunk_positions(start, cch)
-            key_pos = jnp.arange(full.k.shape[2], dtype=jnp.int32)
-            kp = key_pos[None, None, None, :]
-            pq = pos_q[:, None, :, None]
-            valid = jnp.broadcast_to(
-                kp <= pq, (b, full.k.shape[1], cch, full.k.shape[2]))
-            if spec.window > 0:
-                valid = valid & (kp > pq - spec.window)
-            from repro.kernels import ops as kops
-            o = kops.chunk_attention(q, full.k, full.v, valid,
-                                     impl=spec.impl)
+            with jax.named_scope("attn.append"):
+                full = cachelib.full_cache_append_chunk(
+                    cache["full"], k, v, start, chunk_len, active=active)
+            with jax.named_scope("attn.prefill"):
+                pos_q = paging.chunk_positions(start, cch)
+                key_pos = jnp.arange(full.k.shape[2], dtype=jnp.int32)
+                kp = key_pos[None, None, None, :]
+                pq = pos_q[:, None, :, None]
+                valid = jnp.broadcast_to(
+                    kp <= pq, (b, full.k.shape[1], cch, full.k.shape[2]))
+                if spec.window > 0:
+                    valid = valid & (kp > pq - spec.window)
+                from repro.kernels import ops as kops
+                o = kops.chunk_attention(q, full.k, full.v, valid,
+                                         impl=spec.impl)
             cache = {"full": full}
-        x = x + dense(o.reshape(b, cch, -1), p["wo"])
+        with jax.named_scope("attn.out"):
+            x = x + dense(o.reshape(b, cch, -1), p["wo"])
     elif mixer == MIXER_MAMBA2:
         y, st = ssmlib.mamba2_prefill_chunk(
             cfg, p["mamba"], cache["ssm"], h, chunk_len=chunk_len,
@@ -403,16 +408,18 @@ def block_verify_chunk(cfg: ArchConfig, pos: int, p, plan, x, rope, cache,
     spec = attn_spec(cfg, pos, impl)
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     b, kch = x.shape[0], x.shape[1]
-    q, k, v = _qkv(cfg, p, h)
-    cos, sin = rope
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("qkv"):
+        q, k, v = _qkv(cfg, p, h)
+        cos, sin = rope
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     inputs = layoutlib.VerifyInputs(
         q=q, k_new=k, v_new=v, start=start, active=active,
         need_select=need_select)
     o, cache = layoutlib.dispatch_verify_chunk(
         layout, spec, cache, inputs, perm=plan["perm"])
-    x = x + dense(o.reshape(b, kch, -1), p["wo"])
+    with jax.named_scope("attn.out"):
+        x = x + dense(o.reshape(b, kch, -1), p["wo"])
     if cfg.layer_has_ffn(pos):
         x = _ffn_apply(cfg, p, x)
     return x, cache, (k, v)
@@ -443,13 +450,14 @@ def block_decode(cfg: ArchConfig, pos: int, p, plan, x, rope1, cache, *,
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if mixer == MIXER_ATTENTION:
         spec = attn_spec(cfg, pos, impl)
-        q, k, v = _qkv(cfg, p, h)
-        cos1, sin1 = rope1  # (B?, 1, half) at position `length`
-        q = apply_rope(q[:, None], cos1, sin1)[:, 0]
-        k = apply_rope(k[:, None], cos1, sin1)[:, 0]
-        q = hints.decode_qkv(q)
-        k = hints.decode_qkv(k)
-        v = hints.decode_qkv(v)
+        with jax.named_scope("qkv"):
+            q, k, v = _qkv(cfg, p, h)
+            cos1, sin1 = rope1  # (B?, 1, half) at position `length`
+            q = apply_rope(q[:, None], cos1, sin1)[:, 0]
+            k = apply_rope(k[:, None], cos1, sin1)[:, 0]
+            q = hints.decode_qkv(q)
+            k = hints.decode_qkv(k)
+            v = hints.decode_qkv(v)
         if "full" in cache:
             o, full = hattn.full_decode_attention(
                 spec, q, k, v, cache["full"], length, active=active)
@@ -462,7 +470,8 @@ def block_decode(cfg: ArchConfig, pos: int, p, plan, x, rope1, cache, *,
                 layout, spec, cache, inputs, do_select=do_select,
                 perm=plan["perm"])
         b = o.shape[0]
-        x = x + dense(o.reshape(b, -1), p["wo"])
+        with jax.named_scope("attn.out"):
+            x = x + dense(o.reshape(b, -1), p["wo"])
     elif mixer == MIXER_MAMBA2:
         y, st = ssmlib.mamba2_step(cfg, p["mamba"], cache["ssm"], h)
         x = x + y

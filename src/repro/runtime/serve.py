@@ -97,8 +97,9 @@ def make_sample_step(cfg: ArchConfig, scfg: ServeConfig):
     def sample(logits, base, gen, temp, topp, active):
         from repro.serving import sampling
 
-        tok = sampling.sample_tokens(logits, base, gen, temp, topp)
-        return tok, jnp.where(active, gen + 1, gen)
+        with jax.named_scope("sample"):
+            tok = sampling.sample_tokens(logits, base, gen, temp, topp)
+            return tok, jnp.where(active, gen + 1, gen)
     return sample
 
 
@@ -134,7 +135,8 @@ def make_verify_step(cfg: ArchConfig, scfg: ServeConfig, *, k: int):
         logits, state1, stash = M.verify_forward(
             cfg, params, state, tokens, active=active,
             need_select=need_select, impl=scfg.impl, layout=layout)
-        targets = sampling.sample_chunk(logits, base, gen, temp, topp)
+        with jax.named_scope("sample"):
+            targets = sampling.sample_chunk(logits, base, gen, temp, topp)
         matches = tokens[:, 1:] == targets[:, :-1]          # (B, k-1)
         n_nat = 1 + jnp.sum(
             jnp.cumprod(matches.astype(jnp.int32), axis=1), axis=1)
@@ -217,9 +219,10 @@ def make_fused_window_step(cfg: ArchConfig, scfg: ServeConfig, *,
         logits, state = M.decode_step(cfg, params, state, tok,
                                       do_select=False, impl=scfg.impl,
                                       layout=layout, active=act)
-        t = sampling.sample_tokens(logits, base, gen, temp, topp)
-        tok = jnp.where(act, t, tok)
-        gen = jnp.where(act, gen + 1, gen)
+        with jax.named_scope("sample"):
+            t = sampling.sample_tokens(logits, base, gen, temp, topp)
+            tok = jnp.where(act, t, tok)
+            gen = jnp.where(act, gen + 1, gen)
         emitted = emitted + act.astype(jnp.int32)
         act = act & (emitted < budgets)
         return state, tok, act, gen, emitted
@@ -251,8 +254,9 @@ def make_fused_window_step(cfg: ArchConfig, scfg: ServeConfig, *,
                 logits_c, state = M.prefill_chunk(
                     cfg, params, state, ctoks, chunk_len=clens,
                     active=clens > 0, impl=scfg.impl, layout=layout)
-                first = sampling.sample_tokens(
-                    logits_c, base, jnp.zeros_like(gen), temp, topp)
+                with jax.named_scope("sample"):
+                    first = sampling.sample_tokens(
+                        logits_c, base, jnp.zeros_like(gen), temp, topp)
                 tok = jnp.where(fin, first, tok)
                 gen = jnp.where(fin, jnp.ones_like(gen), gen)
                 state, tok, act, gen, emitted = _decode_half(

@@ -85,6 +85,14 @@ from repro.models import model as M
 from repro.runtime import serve as serve_rt
 from repro.serving import sampling as samplib
 
+# Profiler spans on the host: one ``engine.poll`` step span per poll()
+# with a child for each phase of the step (``engine.admit``,
+# ``engine.schedule``, ``engine.chunk``, ``engine.decode``,
+# ``engine.sample``, ``engine.account``), and ``engine.sync``. They sit
+# around phases, never inside a per-slot loop; while no profiler trace is
+# being taken, each costs about a microsecond.
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclasses.dataclass
 class Request:
@@ -673,9 +681,10 @@ class Engine:
                                **samp_shard)
 
         def _sample_one_fn(logits, base, gen, temp, topp):
-            return samplib.sample_tokens(logits[None], base[None],
-                                         gen[None], temp[None],
-                                         topp[None])[0]
+            with jax.named_scope("sample"):
+                return samplib.sample_tokens(logits[None], base[None],
+                                             gen[None], temp[None],
+                                             topp[None])[0]
         self._sample_one = jax.jit(_sample_one_fn)
         # fused decode windows (PR 10): the reuse steps between two
         # selection boundaries collapse into ONE dispatched lax.scan
@@ -779,10 +788,6 @@ class Engine:
         # (identical to `% == 0` per-step; a fused window can jump past
         # the multiple without ever landing on it)
         self._prev_engine_steps = 0
-        # engine-step index of each trace row: lets a latency harness map
-        # token emissions (Completion._step_idx trace rows) to per-step
-        # wall-clock timestamps (benchmarks/serve_throughput.py --arrival)
-        self.trace_engine_steps: List[int] = []
         self._prompts: Dict[int, np.ndarray] = {}        # slot -> prompt
         self._admit_seq = 0                              # FIFO chunk order
         self._queue: deque[Request] = deque()
@@ -1439,45 +1444,51 @@ class Engine:
         prompt completes this step emits its first token from the chunk
         logits and starts decoding next step."""
         b = self.batch
-        self._promote_ready()
-        self._prev_engine_steps = self.stats.engine_steps
-        # fused decode-window routing (PR 10): strictly between two
-        # selection boundaries every decoding slot runs reuse steps
-        # only, so the stretch to the next boundary collapses into ONE
-        # dispatched scan. Boundary steps (any slot due a selection
-        # refresh) and chunk-only steps stay per-step.
-        if (self._fused is not None and b.active.any()
-                and not (b.active
-                         & (b.phase % self.share_window == 0)).any()):
+        with _span("engine.schedule"):
+            self._promote_ready()
+            self._prev_engine_steps = self.stats.engine_steps
+            # fused decode-window routing: strictly between two
+            # selection boundaries every decoding slot runs reuse steps
+            # only, so the stretch to the next boundary collapses into
+            # ONE dispatched scan. Boundary steps (any slot due a
+            # selection refresh) and chunk-only steps stay per-step.
+            fused = (self._fused is not None and b.active.any()
+                     and not (b.active
+                              & (b.phase % self.share_window == 0)).any())
+            chunk_work = (self._schedule_chunks()
+                          if self.prefill_chunk is not None and not fused
+                          else None)
+        if fused:
             with self._mesh_ctx():
                 self._window_once(b.active.copy())
             if self._cost_model is not None:
-                self._maybe_rebalance()
+                with _span("engine.account"):
+                    self._maybe_rebalance()
             return
-        chunk_work = (self._schedule_chunks()
-                      if self.prefill_chunk is not None else None)
         active = b.active.copy()
         if chunk_work is None and not active.any():
             return
         self.stats.engine_steps += 1
         with self._mesh_ctx():
             if chunk_work is not None:
-                toks, clens = chunk_work
-                logits_c, b.serve = self._chunk(
-                    self.params, b.serve, jnp.asarray(toks),
-                    jnp.asarray(clens), jnp.asarray(clens > 0))
-                self.stats.dispatches += 1
-                self.stats.prefill_chunks += 1
-                for slot in np.nonzero(clens)[0]:
-                    slot = int(slot)
-                    b.lengths[slot] += int(clens[slot])
-                    b.prompt_left[slot] -= int(clens[slot])
-                    if b.prompt_left[slot] == 0:
-                        self._finish_prefill(slot, logits_c)
+                with _span("engine.chunk"):
+                    toks, clens = chunk_work
+                    logits_c, b.serve = self._chunk(
+                        self.params, b.serve, jnp.asarray(toks),
+                        jnp.asarray(clens), jnp.asarray(clens > 0))
+                    self.stats.dispatches += 1
+                    self.stats.prefill_chunks += 1
+                    for slot in np.nonzero(clens)[0]:
+                        slot = int(slot)
+                        b.lengths[slot] += int(clens[slot])
+                        b.prompt_left[slot] -= int(clens[slot])
+                        if b.prompt_left[slot] == 0:
+                            self._finish_prefill(slot, logits_c)
             if active.any():
                 self._decode_once(active)
         if self._cost_model is not None:
-            self._maybe_rebalance()
+            with _span("engine.account"):
+                self._maybe_rebalance()
 
     # ------------------------------------------------------------------
     # dynamic rebalancing (sched/cost.py + sched/rebalance.py)
@@ -1617,58 +1628,63 @@ class Engine:
             return self._verify_once(active)
         b = self.batch
         step_idx = self._trace_rows
-        # selection refresh: each slot's own share-window cadence (so a
-        # slot's schedule is independent of the global clock, other
-        # slots, and how its admission was chunked)
-        need = active & (b.phase % self.share_window == 0)
-        if not np.array_equal(self._act_mirror, active):
-            self._act_dev = jnp.asarray(active)
-            self._act_mirror = active.copy()
-        act_dev = self._act_dev
-        if need.any():
-            need_dev = jnp.asarray(need)
-            if self._tier is not None:
-                logits, b.serve = self._tier_select(need, need_dev,
-                                                    act_dev)
+        with _span("engine.decode"):
+            # selection refresh: each slot's own share-window cadence (so
+            # a slot's schedule is independent of the global clock, other
+            # slots, and how its admission was chunked)
+            need = active & (b.phase % self.share_window == 0)
+            if not np.array_equal(self._act_mirror, active):
+                self._act_dev = jnp.asarray(active)
+                self._act_mirror = active.copy()
+            act_dev = self._act_dev
+            if need.any():
+                need_dev = jnp.asarray(need)
+                if self._tier is not None:
+                    logits, b.serve = self._tier_select(need, need_dev,
+                                                        act_dev)
+                else:
+                    logits, b.serve = self._dec_sel(
+                        self.params, b.serve, self._tok, act_dev, need_dev)
+                    self.stats.dispatches += 1
+                self.stats.select_steps += 1
             else:
-                logits, b.serve = self._dec_sel(
-                    self.params, b.serve, self._tok, act_dev, need_dev)
+                logits, b.serve = self._dec_reuse(
+                    self.params, b.serve, self._tok, act_dev)
                 self.stats.dispatches += 1
-            self.stats.select_steps += 1
-        else:
-            logits, b.serve = self._dec_reuse(
-                self.params, b.serve, self._tok, act_dev)
+                self.stats.reuse_steps += 1
+        with _span("engine.sample"):
+            # keep non-active rows of the token feed: a slot that
+            # finished prefilling THIS step already holds its first
+            # token, which this dispatch (captured mask without it) must
+            # not clobber with the sample of an inactive row's garbage
+            # logits (the sampler's temp=0 lane IS argmax, so greedy rows
+            # stay bit-identical to the pre-sampling engine)
+            tok, b.samp_gen = self._sample(logits, b.samp_base, b.samp_gen,
+                                           b.samp_temp, b.samp_topp,
+                                           act_dev)
             self.stats.dispatches += 1
-            self.stats.reuse_steps += 1
-        # keep non-active rows of the token feed: a slot that finished
-        # prefilling THIS step already holds its first token, which this
-        # dispatch (captured mask without it) must not clobber with the
-        # sample of an inactive row's garbage logits (the sampler's
-        # temp=0 lane IS argmax, so greedy rows stay bit-identical to
-        # the pre-sampling engine)
-        tok, b.samp_gen = self._sample(logits, b.samp_base, b.samp_gen,
-                                       b.samp_temp, b.samp_topp, act_dev)
-        self.stats.dispatches += 1
-        self._tok = jnp.where(act_dev, tok, self._tok)
-        self._trace.append(self._tok[None])
-        self._trace_rows += 1
-        self.trace_engine_steps.append(self.stats.engine_steps)
-        self.stats.decode_steps += 1
-        self.stats.occupancy_sum += float(active.mean())
-        for slot in np.nonzero(active)[0]:
-            b.lengths[slot] += 1
-            b.phase[slot] += 1
-            comp = self._live[slot]
-            comp._step_idx.append(step_idx)
-            comp._slot_idx.append(int(slot))
-            self.stats.tokens_out += 1
-            b.remaining[slot] -= 1
-            if b.remaining[slot] <= 0 or b.lengths[slot] >= self.capacity:
-                self._retire(slot)
-        if self._tier_plan is not None:
-            # prefetch/spill for the NEXT share window, one window ahead
-            # of the selection refresh that will consume the pages
-            self._tier_refresh()
+            self._tok = jnp.where(act_dev, tok, self._tok)
+            self._trace.append(self._tok[None])
+        with _span("engine.account"):
+            self._trace_rows += 1
+            self.stats.decode_steps += 1
+            self.stats.occupancy_sum += float(active.mean())
+            for slot in np.nonzero(active)[0]:
+                b.lengths[slot] += 1
+                b.phase[slot] += 1
+                comp = self._live[slot]
+                comp._step_idx.append(step_idx)
+                comp._slot_idx.append(int(slot))
+                self.stats.tokens_out += 1
+                b.remaining[slot] -= 1
+                if (b.remaining[slot] <= 0
+                        or b.lengths[slot] >= self.capacity):
+                    self._retire(slot)
+            if self._tier_plan is not None:
+                # prefetch/spill for the NEXT share window, one window
+                # ahead of the selection refresh that will consume the
+                # pages
+                self._tier_refresh()
 
     def _window_once(self, active: np.ndarray):
         """One fused decode window: every reuse step from here to the
@@ -1679,85 +1695,89 @@ class Engine:
         budget vector alone — a slot emits EXACTLY ``budgets[i]`` tokens
         by construction, so no device readback is needed and the loop
         stays non-blocking."""
-        from repro.sched import window_budgets
-        b = self.batch
-        # reuse steps only read pinned-resident pages (spill candidates
-        # exclude the selection, sink, and local sections), so a fused
-        # window can never cold-miss; any pending refresh plan was
-        # already consumed by the selection step that opened this window
-        assert self._tier_plan is None, "refresh plan crossed a boundary"
-        w = self.share_window
-        residue = int(b.phase[np.nonzero(active)[0][0]] % w)
-        n_useful, budgets = window_budgets(
-            active, b.remaining, b.lengths, capacity=self.capacity,
-            phase_residue=residue, share_window=w,
-            window=self._fused_len)
-        if not np.array_equal(self._act_mirror, active):
-            self._act_dev = jnp.asarray(active)
-            self._act_mirror = active.copy()
-        act_dev = self._act_dev
-        plan = (self._plan_window_chunks(self._fused_len)
-                if self._fused_mix is not None else None)
-        e0 = self.stats.engine_steps
-        if plan is None:
-            trace_blk, b.serve, self._tok, b.samp_gen = self._fused(
-                self.params, b.serve, self._tok, act_dev, b.samp_gen,
-                jnp.asarray(budgets), b.samp_base, b.samp_temp,
-                b.samp_topp)
-        else:
-            toks, clens, finish = plan
-            trace_blk, b.serve, self._tok, b.samp_gen = self._fused_mix(
-                self.params, b.serve, self._tok, act_dev, b.samp_gen,
-                jnp.asarray(budgets), b.samp_base, b.samp_temp,
-                b.samp_topp, jnp.asarray(toks), jnp.asarray(clens),
-                jnp.asarray(finish))
-        self.stats.dispatches += 1
-        self.stats.fused_windows += 1
-        max_e = int(budgets[active].max())
-        chunk_iters = (int((plan[1].sum(axis=1) > 0).sum())
-                       if plan is not None else 0)
-        # the window consumed as many logical engine steps as its
-        # longest-running half (per-step would interleave them 1:1)
-        self.stats.engine_steps += max(max_e, chunk_iters)
-        self.stats.fused_steps += max_e
-        self.stats.decode_steps += max_e
-        self.stats.reuse_steps += max_e
-        self.stats.prefill_chunks += chunk_iters
-        row0 = self._trace_rows
-        self._trace.append(trace_blk[:max_e])
-        self._trace_rows += max_e
-        for j in range(max_e):
-            self.trace_engine_steps.append(e0 + 1 + j)
-            self.stats.occupancy_sum += float(
-                (budgets > j).sum()) / b.max_batch
-        # chunk bookkeeping first (disjoint slot sets): a slot whose
-        # prompt completed in-scan flips to READY exactly where the
-        # per-step mixed step would have flipped it
-        if plan is not None:
-            for j in range(self._fused_len):
-                for slot in np.nonzero(clens[j])[0]:
-                    slot = int(slot)
-                    b.lengths[slot] += int(clens[j, slot])
-                    b.prompt_left[slot] -= int(clens[j, slot])
-                    if finish[j, slot]:
-                        self._finish_prefill_fused(slot, trace_blk, j,
-                                                   e0 + 1 + j)
-        for slot in np.nonzero(active)[0]:
-            slot = int(slot)
-            emitted = int(budgets[slot])
-            comp = self._live[slot]
-            comp._step_idx.extend(range(row0, row0 + emitted))
-            comp._slot_idx.extend([slot] * emitted)
-            b.lengths[slot] += emitted
-            # a survivor's budget is exactly n_useful (any smaller
-            # budget means a stop condition fired → it retires below),
-            # so live phases stay aligned at the next boundary
-            b.phase[slot] += emitted
-            b.remaining[slot] -= emitted
-            self.stats.tokens_out += emitted
-            if (b.remaining[slot] <= 0
-                    or b.lengths[slot] >= self.capacity):
-                self._retire(slot)
+        with _span("engine.decode"):
+            from repro.sched import window_budgets
+            b = self.batch
+            # reuse steps only read pinned-resident pages (spill
+            # candidates exclude the selection, sink, and local sections),
+            # so a fused window can never cold-miss; any pending refresh
+            # plan was already consumed by the selection step that opened
+            # this window
+            assert self._tier_plan is None, (
+                "refresh plan crossed a boundary")
+            w = self.share_window
+            residue = int(b.phase[np.nonzero(active)[0][0]] % w)
+            n_useful, budgets = window_budgets(
+                active, b.remaining, b.lengths, capacity=self.capacity,
+                phase_residue=residue, share_window=w,
+                window=self._fused_len)
+            if not np.array_equal(self._act_mirror, active):
+                self._act_dev = jnp.asarray(active)
+                self._act_mirror = active.copy()
+            act_dev = self._act_dev
+            plan = (self._plan_window_chunks(self._fused_len)
+                    if self._fused_mix is not None else None)
+            e0 = self.stats.engine_steps
+            if plan is None:
+                trace_blk, b.serve, self._tok, b.samp_gen = self._fused(
+                    self.params, b.serve, self._tok, act_dev, b.samp_gen,
+                    jnp.asarray(budgets), b.samp_base, b.samp_temp,
+                    b.samp_topp)
+            else:
+                toks, clens, finish = plan
+                (trace_blk, b.serve, self._tok,
+                 b.samp_gen) = self._fused_mix(
+                    self.params, b.serve, self._tok, act_dev, b.samp_gen,
+                    jnp.asarray(budgets), b.samp_base, b.samp_temp,
+                    b.samp_topp, jnp.asarray(toks), jnp.asarray(clens),
+                    jnp.asarray(finish))
+            self.stats.dispatches += 1
+            self.stats.fused_windows += 1
+        with _span("engine.account"):
+            max_e = int(budgets[active].max())
+            chunk_iters = (int((plan[1].sum(axis=1) > 0).sum())
+                           if plan is not None else 0)
+            # the window consumed as many logical engine steps as its
+            # longest-running half (per-step would interleave them 1:1)
+            self.stats.engine_steps += max(max_e, chunk_iters)
+            self.stats.fused_steps += max_e
+            self.stats.decode_steps += max_e
+            self.stats.reuse_steps += max_e
+            self.stats.prefill_chunks += chunk_iters
+            row0 = self._trace_rows
+            self._trace.append(trace_blk[:max_e])
+            self._trace_rows += max_e
+            for j in range(max_e):
+                self.stats.occupancy_sum += float(
+                    (budgets > j).sum()) / b.max_batch
+            # chunk bookkeeping first (disjoint slot sets): a slot whose
+            # prompt completed in-scan flips to READY exactly where the
+            # per-step mixed step would have flipped it
+            if plan is not None:
+                for j in range(self._fused_len):
+                    for slot in np.nonzero(clens[j])[0]:
+                        slot = int(slot)
+                        b.lengths[slot] += int(clens[j, slot])
+                        b.prompt_left[slot] -= int(clens[j, slot])
+                        if finish[j, slot]:
+                            self._finish_prefill_fused(slot, trace_blk, j,
+                                                       e0 + 1 + j)
+            for slot in np.nonzero(active)[0]:
+                slot = int(slot)
+                emitted = int(budgets[slot])
+                comp = self._live[slot]
+                comp._step_idx.extend(range(row0, row0 + emitted))
+                comp._slot_idx.extend([slot] * emitted)
+                b.lengths[slot] += emitted
+                # a survivor's budget is exactly n_useful (any smaller
+                # budget means a stop condition fired → it retires below),
+                # so live phases stay aligned at the next boundary
+                b.phase[slot] += emitted
+                b.remaining[slot] -= emitted
+                self.stats.tokens_out += emitted
+                if (b.remaining[slot] <= 0
+                        or b.lengths[slot] >= self.capacity):
+                    self._retire(slot)
 
     def _verify_once(self, active: np.ndarray):
         """The speculative decode half of a step: draft k-1 tokens per
@@ -1770,72 +1790,74 @@ class Engine:
         (phase hitting 0 mod share_window), its generation budget, and
         capacity — so selection cadence stays a pure function of the
         slot's own phase and the capacity invariant holds."""
-        b = self.batch
-        k = self.spec_tokens
-        w = self.share_window
-        need = active & (b.phase % w == 0)
-        if not np.array_equal(self._act_mirror, active):
-            self._act_dev = jnp.asarray(active)
-            self._act_mirror = active.copy()
-        act_dev = self._act_dev
-        drafted = self.draft.draft(self, active, k)
-        if k > 1:
-            tokens = jnp.concatenate(
-                [self._tok[:, None],
-                 jnp.asarray(drafted, jnp.int32)], axis=1)
-        else:
-            tokens = self._tok[:, None]
-        max_emit = np.ones((b.max_batch,), np.int64)
-        for slot in np.nonzero(active)[0]:
-            slot = int(slot)
-            r = int(b.phase[slot]) % w
-            window_left = (w - r) if r else w
-            max_emit[slot] = max(1, min(k, window_left,
-                                        int(b.remaining[slot]),
-                                        self.capacity - int(b.lengths[slot])))
-        targets, n_dev, next_dev, b.samp_gen, b.serve = self._verify(
-            self.params, b.serve, tokens, act_dev, jnp.asarray(need),
-            b.samp_base, b.samp_gen, b.samp_temp, b.samp_topp,
-            jnp.asarray(max_emit, jnp.int32))
-        self.stats.dispatches += 1
-        self._tok = jnp.where(act_dev, next_dev, self._tok)
-        if need.any():
-            self.stats.select_steps += 1
-        else:
-            self.stats.reuse_steps += 1
-        # the trace gets k rows per verify step (the coupled targets);
-        # a slot that accepted n of them owns rows [base, base+n)
-        trace_base = self._trace_rows
-        self._trace.append(targets.T)               # (k, B) block
-        self._trace_rows += k
-        for j in range(k):
-            self.trace_engine_steps.append(self.stats.engine_steps)
-        self.stats.decode_steps += 1
-        self.stats.spec_steps += 1
-        self.stats.occupancy_sum += float(active.mean())
-        # the one host sync speculation adds: accepted counts (and the
-        # target tokens, for host-side draft history) per verify step
-        n_host, targets_host = jax.device_get((n_dev, targets))
-        need_hist = self.draft.needs_host_tokens
-        for slot in np.nonzero(active)[0]:
-            slot = int(slot)
-            nb = int(n_host[slot])
-            comp = self._live[slot]
-            comp._step_idx.extend(range(trace_base, trace_base + nb))
-            comp._slot_idx.extend([slot] * nb)
-            b.lengths[slot] += nb
-            b.phase[slot] += nb
-            b.remaining[slot] -= nb
-            self._spec_emitted[slot] += nb
-            self.stats.tokens_out += nb
-            self.stats.spec_slot_steps += 1
-            self.stats.spec_drafted += k - 1
-            self.stats.spec_accepted += nb
-            if need_hist:
-                self._spec_history[slot].extend(
-                    int(t) for t in targets_host[slot, :nb])
-            if b.remaining[slot] <= 0 or b.lengths[slot] >= self.capacity:
-                self._retire(slot)
+        with _span("engine.decode"):
+            b = self.batch
+            k = self.spec_tokens
+            w = self.share_window
+            need = active & (b.phase % w == 0)
+            if not np.array_equal(self._act_mirror, active):
+                self._act_dev = jnp.asarray(active)
+                self._act_mirror = active.copy()
+            act_dev = self._act_dev
+            drafted = self.draft.draft(self, active, k)
+            if k > 1:
+                tokens = jnp.concatenate(
+                    [self._tok[:, None],
+                     jnp.asarray(drafted, jnp.int32)], axis=1)
+            else:
+                tokens = self._tok[:, None]
+            max_emit = np.ones((b.max_batch,), np.int64)
+            for slot in np.nonzero(active)[0]:
+                slot = int(slot)
+                r = int(b.phase[slot]) % w
+                window_left = (w - r) if r else w
+                max_emit[slot] = max(1, min(
+                    k, window_left, int(b.remaining[slot]),
+                    self.capacity - int(b.lengths[slot])))
+            targets, n_dev, next_dev, b.samp_gen, b.serve = self._verify(
+                self.params, b.serve, tokens, act_dev, jnp.asarray(need),
+                b.samp_base, b.samp_gen, b.samp_temp, b.samp_topp,
+                jnp.asarray(max_emit, jnp.int32))
+            self.stats.dispatches += 1
+        with _span("engine.sample"):
+            self._tok = jnp.where(act_dev, next_dev, self._tok)
+        with _span("engine.account"):
+            if need.any():
+                self.stats.select_steps += 1
+            else:
+                self.stats.reuse_steps += 1
+            # the trace gets k rows per verify step (the coupled targets);
+            # a slot that accepted n of them owns rows [base, base+n)
+            trace_base = self._trace_rows
+            self._trace.append(targets.T)               # (k, B) block
+            self._trace_rows += k
+            self.stats.decode_steps += 1
+            self.stats.spec_steps += 1
+            self.stats.occupancy_sum += float(active.mean())
+            # the one host sync speculation adds: accepted counts (and the
+            # target tokens, for host-side draft history) per verify step
+            n_host, targets_host = jax.device_get((n_dev, targets))
+            need_hist = self.draft.needs_host_tokens
+            for slot in np.nonzero(active)[0]:
+                slot = int(slot)
+                nb = int(n_host[slot])
+                comp = self._live[slot]
+                comp._step_idx.extend(range(trace_base, trace_base + nb))
+                comp._slot_idx.extend([slot] * nb)
+                b.lengths[slot] += nb
+                b.phase[slot] += nb
+                b.remaining[slot] -= nb
+                self._spec_emitted[slot] += nb
+                self.stats.tokens_out += nb
+                self.stats.spec_slot_steps += 1
+                self.stats.spec_drafted += k - 1
+                self.stats.spec_accepted += nb
+                if need_hist:
+                    self._spec_history[slot].extend(
+                        int(t) for t in targets_host[slot, :nb])
+                if (b.remaining[slot] <= 0
+                        or b.lengths[slot] >= self.capacity):
+                    self._retire(slot)
 
     def finalize(self):
         """Materialize completion tokens from the device-side trace.
@@ -1868,21 +1890,19 @@ class Engine:
         simulators, latency harnesses) need not reach into the
         internals. Returns True if the step dispatched any work."""
         before = self.stats.engine_steps
-        self._admit()
-        self.step()
+        with jax.profiler.StepTraceAnnotation("engine.poll",
+                                              step_num=before):
+            with _span("engine.admit"):
+                self._admit()
+            self.step()
         return self.stats.engine_steps > before
 
     def sync(self):
         """Block until the device has caught up with the dispatched
         steps (latency harnesses call this per step for honest
         timestamps; the throughput path never does)."""
-        jax.block_until_ready(self._tok)
-
-    def token_engine_steps(self, comp: Completion) -> List[int]:
-        """Engine-step index at which each of ``comp``'s post-first
-        tokens was emitted (pairs with ``Completion.first_token_step``
-        for per-token latency accounting)."""
-        return [self.trace_engine_steps[r] for r in comp._step_idx]
+        with _span("engine.sync"):
+            jax.block_until_ready(self._tok)
 
     def run(self, requests: Optional[Sequence[Request]] = None
             ) -> Dict[int, Completion]:
@@ -1911,7 +1931,6 @@ class Engine:
         self._trace.clear()
         self._trace_rows = 0
         self._prev_engine_steps = 0
-        self.trace_engine_steps.clear()
         self.completions = {}
         self.stats = EngineStats()
         # the cooldown window is measured in engine_steps, which just

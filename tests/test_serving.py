@@ -500,6 +500,28 @@ def test_chunked_prefill_property_chunk_x_prompt(model):
     check()
 
 
+def _poll_emits(eng):
+    """One ``Engine.poll()``, read from ``Engine.batch`` before and after:
+    the uids admitted, the uids whose first token it made, and the
+    decode tokens it made per uid."""
+    b = eng.batch
+    uid0, len0, pref0 = b.uid.copy(), b.lengths.copy(), b.prefilling.copy()
+    eng.poll()
+    out = {"admitted": set(), "first": set(), "decoded": {}}
+    for s in range(len(b.uid)):
+        before, after = int(uid0[s]), int(b.uid[s])
+        if after >= 0 and after != before:
+            out["admitted"].add(after)
+            if not b.prefilling[s]:   # its prompt is done within the poll
+                out["first"].add(after)
+        elif before >= 0:             # same occupant (-1 once retired)
+            if pref0[s] and not b.prefilling[s]:
+                out["first"].add(before)
+            elif not pref0[s] and b.lengths[s] > len0[s]:
+                out["decoded"][before] = int(b.lengths[s] - len0[s])
+    return out
+
+
 def test_chunked_decode_continues_during_long_prefill(model):
     """The no-head-of-line acceptance property, step-exact: while a
     max-bucket prompt chunk-prefills over several engine steps, a
@@ -514,18 +536,20 @@ def test_chunked_decode_continues_during_long_prefill(model):
                      prefill_chunk=prefill_chunk)
         eng.submit(Request(uid=0, prompt=_prompt(cfg, 16, 1), max_new=30))
         steps = 0
+        polls = []
         while eng.busy():
             if steps == 2:   # long prompt arrives while uid 0 decodes
                 eng.submit(Request(uid=1, prompt=_prompt(cfg, 24, 2),
                                    max_new=3))
-            eng.poll()
+            polls.append(_poll_emits(eng))
             steps += 1
         eng.finalize()
-        long_c = eng.completions[1]
-        other = eng.completions[0]
-        during = sum(
-            1 for es in eng.token_engine_steps(other)
-            if long_c.admitted_engine_step < es < long_c.first_token_step)
+        # polls from the one that admitted uid 1 up to (not including)
+        # the one that delivered its first token
+        admit = next(i for i, p in enumerate(polls) if 1 in p["admitted"])
+        first = next(i for i, p in enumerate(polls) if 1 in p["first"])
+        during = sum(polls[i]["decoded"].get(0, 0)
+                     for i in range(admit, first))
         return eng, during
 
     eng_c, during_c = serve(prefill_chunk=6)
