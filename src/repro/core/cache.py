@@ -16,6 +16,7 @@ functions and be sharded leaf-wise.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 
 import jax
@@ -184,7 +185,26 @@ def paged_cache_append(cache: PagedCache, k_new, v_new, length, active=None):
 
     Metadata for the page is updated incrementally (running min/max).
     No-eviction layout: page index is position//P (capacity covers max ctx).
+
+    The leaves may carry leading layer axes — the decode layer scan's
+    stacked cache, k/v_pages (L, B, Hr, C, P, D) with k_new/v_new
+    (L, B, Hr, D), every layer's token at the one position ``length``.
+    Those axes fold into the batch axis (a reshape, free), so one write
+    per leaf puts every layer's row in place.
     """
+    lead = cache.k_pages.shape[:-5]
+    if lead:
+        b = cache.k_pages.shape[len(lead)]
+        n = math.prod(lead)
+        fold = lambda a: a.reshape((-1,) + a.shape[len(lead) + 1:])
+        if _is_ragged(length, active):
+            length = jnp.tile(
+                jnp.broadcast_to(length, (b,)).astype(jnp.int32), n)
+            active = None if active is None else jnp.tile(
+                jnp.asarray(active).reshape(b), n)
+        out = paged_cache_append(jax.tree.map(fold, cache), fold(k_new),
+                                 fold(v_new), length, active=active)
+        return jax.tree.map(lambda a, like: a.reshape(like.shape), out, cache)
     p = cache.k_pages.shape[3]
     if not _is_ragged(length, active):
         page = length // p
@@ -206,13 +226,10 @@ def paged_cache_append(cache: PagedCache, k_new, v_new, length, active=None):
             cache.tau_min, jnp.minimum(old_min, kf), (0, 0, page, 0))
         tau_max = jax.lax.dynamic_update_slice(
             cache.tau_max, jnp.maximum(old_max, kf), (0, 0, page, 0))
-        start = jax.lax.dynamic_update_slice(
-            cache.page_start,
-            jnp.broadcast_to(page * p, cache.page_start.shape[:2])[:, :, None].astype(jnp.int32),
-            (0, 0, page))
         return dataclasses.replace(
             cache, k_pages=k_pages, v_pages=v_pages,
-            tau_min=tau_min, tau_max=tau_max, page_start=start)
+            tau_min=tau_min, tau_max=tau_max,
+            page_start=paged_start_append(cache.page_start, length, page=p))
 
     b, h, c, _, _ = cache.k_pages.shape
     lb = jnp.broadcast_to(length, (b,)).astype(jnp.int32)
@@ -221,29 +238,58 @@ def paged_cache_append(cache: PagedCache, k_new, v_new, length, active=None):
     bi = jnp.arange(b)[:, None]
     hi = jnp.arange(h)[None, :]
     pg = jnp.broadcast_to(page[:, None], (b, h))
-    of = jnp.broadcast_to(off[:, None], (b, h))
     act = _row_mask(active, b)
     a3 = act[:, None, None]
-    k_wr = jnp.where(a3, k_new.astype(cache.k_pages.dtype),
-                     cache.k_pages[bi, hi, pg, of])
-    v_wr = jnp.where(a3, v_new.astype(cache.v_pages.dtype),
-                     cache.v_pages[bi, hi, pg, of])
+    # whole pages are read and written back, not single rows: a scatter of
+    # (D,)-rows makes the TPU compiler transpose the whole bf16 page buffer
+    # through two copies when D is narrower than a lane tile (head_dim 64)
+    put = ((jnp.arange(p)[None, :] == off[:, None]) & act[:, None])[
+        :, None, :, None]
+    k_wr = jnp.where(put, k_new[:, :, None, :].astype(cache.k_pages.dtype),
+                     cache.k_pages[bi, hi, pg])
+    v_wr = jnp.where(put, v_new[:, :, None, :].astype(cache.v_pages.dtype),
+                     cache.v_pages[bi, hi, pg])
     kf = k_new.astype(jnp.float32)
     old_min = cache.tau_min[bi, hi, pg]
     old_max = cache.tau_max[bi, hi, pg]
     min_wr = jnp.where(a3, jnp.minimum(old_min, kf), old_min)
     max_wr = jnp.where(a3, jnp.maximum(old_max, kf), old_max)
-    start_wr = jnp.where(act[:, None], jnp.broadcast_to((page * p)[:, None],
-                                                        (b, h)),
-                         cache.page_start[bi, hi, pg])
     return dataclasses.replace(
         cache,
-        k_pages=cache.k_pages.at[bi, hi, pg, of].set(k_wr),
-        v_pages=cache.v_pages.at[bi, hi, pg, of].set(v_wr),
+        k_pages=cache.k_pages.at[bi, hi, pg].set(k_wr),
+        v_pages=cache.v_pages.at[bi, hi, pg].set(v_wr),
         tau_min=cache.tau_min.at[bi, hi, pg].set(min_wr),
         tau_max=cache.tau_max.at[bi, hi, pg].set(max_wr),
-        page_start=cache.page_start.at[bi, hi, pg].set(
-            start_wr.astype(jnp.int32)))
+        page_start=paged_start_append(cache.page_start, length, page=p,
+                                      active=active))
+
+
+def paged_start_append(page_start, length, *, page: int, active=None):
+    """``page_start`` (B, Hr, C) after appending a token at ``length``:
+    the page taking it starts at page * P (rows of inactive slots are
+    unchanged). The page-start part of ``paged_cache_append``, and
+    idempotent: applying it twice leaves what applying it once does."""
+    if not _is_ragged(length, active):
+        pg = length // page
+        return jax.lax.dynamic_update_slice(
+            page_start,
+            jnp.broadcast_to(pg * page, page_start.shape[:2])[
+                :, :, None].astype(jnp.int32),
+            (0, 0, pg))
+    b, h, c = page_start.shape
+    lb = jnp.broadcast_to(length, (b,)).astype(jnp.int32)
+    pg = jnp.broadcast_to(jnp.clip(lb // page, 0, c - 1)[:, None], (b, h))
+    bi = jnp.arange(b)[:, None]
+    hi = jnp.arange(h)[None, :]
+    start_wr = jnp.where(_row_mask(active, b)[:, None], pg * page,
+                         page_start[bi, hi, pg])
+    return page_start.at[bi, hi, pg].set(start_wr.astype(jnp.int32))
+
+
+#: the PagedCache leaves the decode layer scan reads in place and writes
+#: once after the scan (``paged_cache_append`` on the stacked cache); the
+#: rest are small and ride the scan
+PAGE_LEAVES = ("k_pages", "v_pages", "tau_min", "tau_max")
 
 
 def sharded_paged_append(k_pages, v_pages, tau_min, tau_max, page_start,

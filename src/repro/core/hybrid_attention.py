@@ -323,6 +323,54 @@ def decode_attention(
     page selection / importance update; the rest keep their cached
     selection, exactly as if the select step had not run for them).
     """
+    out, paged, stream, rows = decode_attention_rows(
+        spec, q, k_new, v_new, paged, stream, length, do_select=do_select,
+        perm=perm, active=active, need_select=need_select)
+    if rows is not None:
+        with jax.named_scope("attn.append"):
+            paged = cachelib.paged_cache_append(paged, *rows, length,
+                                                active=active)
+    return out, paged, stream
+
+
+def decode_attention_rows(
+    spec: AttnSpec,
+    q: Array,
+    k_new: Array,
+    v_new: Array,
+    paged: cachelib.PagedCache,
+    stream: cachelib.StreamCache,
+    length: Array,
+    *,
+    do_select: bool,
+    perm: Array | None = None,
+    active: Array | None = None,
+    need_select: Array | None = None,
+    layer: Array | None = None,
+):
+    """``decode_attention`` with the retrieval heads' page append left to
+    the caller: returns (out, paged', stream', rows), where paged' has
+    ``page_start`` (and, selecting, ``sel_idx``/``importance``) advanced
+    and its k/v pages and tau metadata as given, and rows = (k_r, v_r)
+    (B, Hr, D) is this token's retrieval-head K/V for
+    ``cache.paged_cache_append`` (None without retrieval heads).
+
+    The retrieval heads attend before the append: they gather from the
+    pages as they were and patch the token into the gathered copy at its
+    row (``paging.patch_token_row``): attention over it is bitwise
+    attention over a gather after the append. Selection reads tau from
+    before the append too: the page taking the token is a local page,
+    which scoring masks, so its metadata cannot change a score — hence
+    ``local`` > 0 is required.
+
+    ``layer`` (a traced scalar) is the decode layer scan's in-place form:
+    ``paged.k_pages``/``v_pages``/``tau_min``/``tau_max`` are then the
+    layer-stacked buffers (L, B, Hr, C, ...) read at ``layer`` (the
+    gather indexes the stack; a select step reads that layer's tau), and
+    the scan writes every layer's rows once after it
+    (``cache.paged_cache_append`` on the stacked cache), so the pages
+    never pass through the scan's inputs or outputs.
+    """
     h2 = spec.h2
     g = spec.group
     nr = spec.n_retrieval
@@ -336,18 +384,28 @@ def decode_attention(
     k_r, k_s = kp[:, :nr], kp[:, nr:]
     v_r, v_s = vp[:, :nr], vp[:, nr:]
     ctx = length + 1
+    at_layer = (lambda a: a) if layer is None else (
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False))
 
     outs = []
+    rows = None
     if nr > 0:
+        if h2.local <= 0:
+            raise ValueError(
+                "decode attends before it appends, which needs the page "
+                "taking the token to be a local page: h2eal.local must be "
+                f"> 0 (got {h2.local})")
         with jax.named_scope("attn.append"):
-            paged = cachelib.paged_cache_append(paged, k_r, v_r, length,
-                                                active=active)
+            paged = dataclasses.replace(
+                paged, page_start=cachelib.paged_start_append(
+                    paged.page_start, length, page=h2.page_size,
+                    active=active))
         if do_select:
             with jax.named_scope("attn.select"):
                 scores = paging.score_pages(
-                    q_r, paged.tau_min, paged.tau_max, paged.page_start,
-                    ctx, sink=h2.sink, local=h2.local, page=h2.page_size,
-                    impl=spec.impl)
+                    q_r, at_layer(paged.tau_min), at_layer(paged.tau_max),
+                    paged.page_start, ctx, sink=h2.sink, local=h2.local,
+                    page=h2.page_size, impl=spec.impl)
                 sel = paging.select_pages(scores, h2.top_k_pages)
                 imp = paging.accumulate_importance(paged.importance, scores)
                 if need_select is not None:
@@ -360,13 +418,17 @@ def decode_attention(
                 paged.sel_idx, ctx, sink=h2.sink, local=h2.local,
                 page=h2.page_size)
             gk, gv = paging.gather_pages(paged.k_pages, paged.v_pages,
-                                         slots)
+                                         slots, layer=layer)
+            gk, gv = paging.patch_token_row(
+                gk, gv, k_r, v_r, length, sink=h2.sink, local=h2.local,
+                page=h2.page_size, active=active)
         with jax.named_scope("attn.retrieval"):
             valid = paging.token_validity(
                 slots, paged.page_start, ctx, sink=h2.sink, local=h2.local,
                 page=h2.page_size, top_k=h2.top_k_pages)
             outs.append(kops.paged_attention(q_r, gk, gv, valid,
                                              impl=spec.impl))
+        rows = (k_r, v_r)
     if spec.n_streaming > 0:
         with jax.named_scope("attn.append"):
             stream = cachelib.stream_cache_append(
@@ -382,7 +444,7 @@ def decode_attention(
     with jax.named_scope("attn.out"):
         out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
         out = _permute_q(out, _inverse_perm(perm), g)
-    return out, paged, stream
+    return out, paged, stream, rows
 
 
 # ---------------------------------------------------------------------------

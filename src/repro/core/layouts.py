@@ -36,6 +36,10 @@ example):
   positional signatures of ``core/hybrid_attention.py`` (which remain
   as the underlying bodies and as deprecated direct-call aliases for
   one release).
+* ``decode_rows(spec, state, inputs, *, layer, ...)`` (optional) — the
+  decode step as the model's layer scan runs it: paged leaves read in
+  place from the layer-stacked buffers, the new rows returned for one
+  write after the scan.
 
 Registered layouts:
 
@@ -284,6 +288,16 @@ class AttentionLayout:
             f"layout {self.name!r} does not support ragged "
             f"(continuous-batching) decode")
 
+    #: ``decode_rows(spec, state, inputs, *, layer, do_select, perm)
+    #: -> (out, new state, rows)``: the decode step of the model's layer
+    #: scan with the paged leaves read in place (``state["paged"]``'s
+    #: ``cache.PAGE_LEAVES`` are the layer-stacked buffers, read at
+    #: ``layer``) and the retrieval heads' rows returned for one write
+    #: after the scan — see hybrid_attention.decode_attention_rows. None:
+    #: the layout appends inside its decode hook, and the scan threads
+    #: its whole cache through.
+    decode_rows = None
+
     # -- fused decode windows (PR 10) --------------------------------------
     def decode_window(self, body, carry, xs, *, length: int):
         """Run ``length`` reuse decode steps as one fused program.
@@ -453,6 +467,15 @@ class DefaultLayout(AttentionLayout):
     # the default body handles scalar and (B,) lengths uniformly
     ragged_decode = decode
 
+    def decode_rows(self, spec, state, inputs, *, layer, do_select,
+                    perm=None):
+        out, paged, stream, rows = hattn.decode_attention_rows(
+            spec, inputs.q, inputs.k_new, inputs.v_new,
+            state["paged"], state["stream"], inputs.lengths,
+            do_select=do_select, perm=perm, active=inputs.active,
+            need_select=inputs.need_select, layer=layer)
+        return out, {"paged": paged, "stream": stream}, rows
+
     # speculative verify is a single-program body for every layout: like
     # the chunked-prefill body, its masks are driven by absolute
     # positions/metadata, and _chunk_phys_shards() maps the fixed page
@@ -617,6 +640,9 @@ class CoplaceShmapLayout(CoplaceLayout):
         return out, {"paged": paged, "stream": stream}
 
     ragged_decode = decode
+    # the shard_map body appends on the owner shard itself
+    # (cache.sharded_paged_append), so the layer scan threads its caches
+    decode_rows = None
 
 
 register_layout(DefaultLayout())

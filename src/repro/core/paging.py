@@ -172,14 +172,69 @@ def coplace_attended_slots(
          fixed_phys[:, :, n_sink:]], axis=2)
 
 
-def gather_pages(k_pages: Array, v_pages: Array, slots: Array):
-    """k/v_pages: (B, H, C, P, D); slots: (B, H, N) -> (B, H, N*P, D) each."""
-    b, h, c, p, d = k_pages.shape
-    n = slots.shape[2]
-    sc = jnp.maximum(slots, 0)[:, :, :, None, None]
-    k = jnp.take_along_axis(k_pages, sc, axis=2)
-    v = jnp.take_along_axis(v_pages, sc, axis=2)
+def gather_pages(k_pages: Array, v_pages: Array, slots: Array,
+                 layer: Array | None = None):
+    """k/v_pages: (B, H, C, P, D); slots: (B, H, N) -> (B, H, N*P, D) each.
+
+    With ``layer``, k/v_pages are the layer-stacked (L, B, H, C, P, D)
+    buffers of the decode layer scan, read at ``layer`` in place: the
+    layer index is part of the gather's indices, so no layer slice is
+    materialised. Either way a slot past the page count reads NaN (the
+    gather's fill) and token_validity masks it.
+    """
+    b, h, n = slots.shape
+    p, d = k_pages.shape[-2:]
+    if layer is None:
+        sc = jnp.maximum(slots, 0)[:, :, :, None, None]
+        k = jnp.take_along_axis(k_pages, sc, axis=2)
+        v = jnp.take_along_axis(v_pages, sc, axis=2)
+    else:
+        idx = (layer, jnp.arange(b)[:, None, None],
+               jnp.arange(h)[None, :, None], jnp.maximum(slots, 0))
+        k = k_pages.at[idx].get(mode="fill")
+        v = v_pages.at[idx].get(mode="fill")
     return k.reshape(b, h, n * p, d), v.reshape(b, h, n * p, d)
+
+
+def patch_token_row(gk: Array, gv: Array, k_new: Array, v_new: Array,
+                    length: Array, *, sink: int, local: int, page: int,
+                    active: Array | None = None):
+    """Write this step's token into gathered page buffers.
+
+    gk/gv: (B, H, N*P, D) gathered from ``attended_page_slots`` of the
+    pages BEFORE the token at position ``length`` (scalar or (B,)) was
+    appended; k_new/v_new: (B, H, D). The token goes to its row in the
+    one gathered copy of its page that ``token_validity`` admits for
+    that position: the sink copy while the page is a sink page, else the
+    local copy. Any other copy of the page is masked there, so attention
+    over the result is bitwise attention over what ``gather_pages`` reads
+    after ``cache.paged_cache_append``. Only the sink and local sections
+    are rewritten, in place. Rows of inactive slots stay as gathered:
+    their append writes nothing.
+    """
+    b = gk.shape[0]
+    n = gk.shape[2] // page
+    n_sink, n_local = page_counts(sink=sink, local=local, page=page)
+    lb = _ctx_batched(length, b)
+    pg = lb // page
+    first_local = _first_local_page(lb + 1, local=local, page=page)
+    slot = jnp.where(pg < n_sink, pg, n - n_local + pg - first_local)
+    row = slot * page + lb % page
+    act = jnp.ones((b,), bool) if active is None else \
+        jnp.asarray(active).reshape(b)
+
+    def put(g, new):
+        for lo, size in ((0, n_sink * page), ((n - n_local) * page,
+                                              n_local * page)):
+            hit = (jnp.arange(size)[None, :] == (row - lo)[:, None]) & \
+                act[:, None]
+            part = jnp.where(hit[:, None, :, None],
+                             new[:, :, None, :].astype(g.dtype),
+                             g[:, :, lo:lo + size])
+            g = jax.lax.dynamic_update_slice_in_dim(g, part, lo, axis=2)
+        return g
+
+    return put(gk, k_new), put(gv, v_new)
 
 
 def token_validity(

@@ -5,6 +5,7 @@ stack runs as ``lax.scan`` over periods (see transformer.py).
 """
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Any
 
@@ -12,6 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.core.cache import PAGE_LEAVES, paged_cache_append
+from repro.core.layouts import get_layout
 from repro.models import transformer as T
 from repro.models.layers import rms_norm, rope_cos_sin
 
@@ -335,18 +338,35 @@ def decode_step(cfg: ArchConfig, params, state, token, *, plan=None,
         rope1 = (rope1[0][:, None], rope1[1][:, None])  # (·, 1, half)
     n_per, n_rem = T.layer_layout(cfg)
     p_len = T.period_len(cfg)
+    # paged caches of a layout with a decode_rows hook stay out of the
+    # scan's inputs and outputs: the scan reads their page leaves in place
+    # and each layer's new rows are written once, after it
+    pages = {}
+    if get_layout(layout).decode_rows is not None:
+        pages = {key: {f: getattr(c["paged"], f) for f in PAGE_LEAVES}
+                 for key, c in state["blocks"].items()
+                 if isinstance(c, dict) and "paged" in c}
+
+    def without_pages(c):
+        return {**c, "paged": dataclasses.replace(
+            c["paged"], **dict.fromkeys(PAGE_LEAVES))}
 
     def period_fn(x, xs):
-        params_p, plan_p, cache_p = xs
+        params_p, plan_p, cache_p, layer = xs
         new_caches = {}
         for pos in range(p_len):
-            x, c = T.block_decode(cfg, pos, params_p[f"pos{pos}"],
-                                  plan_p[f"pos{pos}"], x, rope1,
-                                  cache_p[f"pos{pos}"], length=length,
+            key = f"pos{pos}"
+            cache = cache_p[key]
+            if key in pages:
+                cache = {**cache, "paged": dataclasses.replace(
+                    cache["paged"], **pages[key])}
+            x, c = T.block_decode(cfg, pos, params_p[key], plan_p[key], x,
+                                  rope1, cache, length=length,
                                   do_select=do_select, impl=impl,
                                   layout=layout, active=active,
-                                  need_select=need_select)
-            new_caches[f"pos{pos}"] = c
+                                  need_select=need_select,
+                                  layer=layer if key in pages else None)
+            new_caches[key] = without_pages(c) if key in pages else c
         return x, new_caches
 
     new_len = length + 1
@@ -355,9 +375,20 @@ def decode_step(cfg: ArchConfig, params, state, token, *, plan=None,
     new_state: dict[str, Any] = {"length": new_len, "blocks": {},
                                  "rem": {}}
     if n_per > 0:
+        blocks = {key: without_pages(c) if key in pages else c
+                  for key, c in state["blocks"].items()}
         x, caches = jax.lax.scan(
-            period_fn, x,
-            (params["blocks"], plan["blocks"], state["blocks"]))
+            period_fn, x, (params["blocks"], plan["blocks"], blocks,
+                           jnp.arange(n_per, dtype=jnp.int32)))
+        for key, leaves in pages.items():
+            c = dict(caches[key])
+            rows = c.pop("rows")
+            paged = dataclasses.replace(c["paged"], **leaves)
+            if rows is not None:
+                with jax.named_scope("attn.append"):
+                    paged = paged_cache_append(paged, *rows, length,
+                                               active=active)
+            caches[key] = {**c, "paged": paged}
         new_state["blocks"] = caches
     for r in range(n_rem):
         x, c = T.block_decode(cfg, r, params["rem"][f"rem{r}"],

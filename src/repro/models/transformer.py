@@ -440,10 +440,16 @@ def block_verify_append(cfg: ArchConfig, pos: int, plan, cache, kv, *,
 
 def block_decode(cfg: ArchConfig, pos: int, p, plan, x, rope1, cache, *,
                  length, do_select: bool, impl="ref", layout=None,
-                 active=None, need_select=None):
+                 active=None, need_select=None, layer=None):
     """Decode one token. x: (B, d). ``length`` is scalar (lockstep) or
     (B,) per-slot (continuous batching); ``active``/``need_select`` are the
-    ragged path's per-slot masks (see core/hybrid_attention.py)."""
+    ragged path's per-slot masks (see core/hybrid_attention.py).
+
+    ``layer`` (the layer scan's index; paged caches of layouts with a
+    ``decode_rows`` hook only): the cache's paged leaves are the
+    layer-stacked buffers, read in place, and the returned cache carries
+    the retrieval heads' rows under ``"rows"`` for models/model.py to
+    write after the scan."""
     from repro.runtime import hints
     p = hints.unshard_block_params(p)
     mixer = cfg.mixer_for_layer(pos)
@@ -466,9 +472,15 @@ def block_decode(cfg: ArchConfig, pos: int, p, plan, x, rope1, cache, *,
             inputs = layoutlib.DecodeInputs(
                 q=q, k_new=k, v_new=v, lengths=length, active=active,
                 need_select=need_select)
-            o, cache = layoutlib.dispatch_decode(
-                layout, spec, cache, inputs, do_select=do_select,
-                perm=plan["perm"])
+            if layer is None:
+                o, cache = layoutlib.dispatch_decode(
+                    layout, spec, cache, inputs, do_select=do_select,
+                    perm=plan["perm"])
+            else:
+                o, cache, rows = layoutlib.get_layout(layout).decode_rows(
+                    spec, cache, inputs, layer=layer, do_select=do_select,
+                    perm=plan["perm"])
+                cache = {**cache, "rows": rows}
         b = o.shape[0]
         with jax.named_scope("attn.out"):
             x = x + dense(o.reshape(b, -1), p["wo"])

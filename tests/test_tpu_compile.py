@@ -8,6 +8,7 @@ H2EAL split: 3 retrieval kv heads, 2 streaming). Nothing runs; a
 compile that passes is not a chip run. Each compiled program must
 contain the kernel (``tpu_custom_call``).
 """
+import dataclasses
 import os
 
 import jax
@@ -115,3 +116,42 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, dtype):
             for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("do_select", [True, False], ids=["select", "reuse"])
+def test_decode_step_temporaries_hold_no_state_copy_v5e(one_chip, do_select,
+                                                        monkeypatch):
+    """The ragged decode program at Mistral-7B widths (two layers, bf16,
+    8 slots of 32,768 tokens, the paper's H2EAL setting) compiles for v5e
+    with temporaries below one layer's k pages: the layer scan reads the
+    paged cache in place instead of copying the serve state. (One layer
+    trips a check of the TPU compiler's scatter emitter, with or without
+    the in-place scan.)"""
+    from repro.configs import get_arch
+    from repro.kernels import ops
+    from repro.models import model as M
+    from repro.runtime import serve as serve_rt
+
+    # the kernels ask the default backend (the CPU) whether to interpret
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    b, cap = 8, 32768
+    cfg = dataclasses.replace(get_arch("mistral-7b"), num_layers=2)
+    params = jax.eval_shape(lambda: M.init_params(
+        cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16))
+    state = jax.eval_shape(lambda p: M.prefill(
+        cfg, p, jnp.zeros((b, 64), jnp.int32), capacity=cap)[1], params)
+    state["length"] = jax.ShapeDtypeStruct((b,), jnp.int32)
+    put = lambda t: jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), t)
+    vec = lambda dt: jax.ShapeDtypeStruct((b,), dt, sharding=one_chip)
+    fn = jax.jit(serve_rt.make_ragged_decode_step(
+        cfg, serve_rt.ServeConfig(capacity=cap, impl="pallas"),
+        do_select=do_select), donate_argnums=(1,))
+    args = (put(params), put(state), vec(jnp.int32), vec(bool))
+    args += (vec(bool),) if do_select else ()
+    compiled = fn.lower(*args).compile()
+    k_pages = state["blocks"]["pos0"]["paged"].k_pages
+    layer_bytes = k_pages.size * k_pages.dtype.itemsize // cfg.num_layers
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert "tpu_custom_call" in compiled.as_text()
+    assert temp < layer_bytes, (temp, layer_bytes)
